@@ -42,6 +42,7 @@ import time
 import numpy as np
 
 from repro.core import LDAConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 # ---------------------------------------------------------------------------
 # model constants (documented in docs/divi.md §benchmark)
@@ -179,6 +180,7 @@ def dist_report(json_path: str | None, *, dryrun: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_dist.json",
                     help="where to write the distributed record")

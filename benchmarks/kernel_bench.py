@@ -55,6 +55,7 @@ from repro.core.math import exp_dirichlet_expectation
 from repro.data import PAPER_CORPORA, make_corpus
 from repro.kernels import lda_estep, ops, ref
 from repro.launch.hlo_analysis import pallas_call_sites
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def rows():
@@ -526,6 +527,7 @@ def estep_rows():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--estep-json", default="BENCH_estep.json",
                     help="where to write the fused-vs-sweeps record")

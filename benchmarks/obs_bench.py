@@ -52,6 +52,7 @@ from repro.data import PAPER_CORPORA, make_corpus
 from repro.lda import LDA
 from repro.obs import (SpanRecorder, Telemetry, chrome_trace_from_jsonl,
                        roofline_from_trace, validate_jsonl)
+from repro.launch.compile_cache import enable_compile_cache
 
 ESTEP_ITERS = 15
 BATCH = 32
@@ -203,6 +204,7 @@ def obs_report(json_path: str | None = None, *,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_obs.json",
                     help="where to write the observability record")

@@ -22,6 +22,7 @@ import os
 from typing import Dict, List, Optional
 
 from repro.configs import ARCHS, get_shape
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.roofline import HBM_GB, HW  # the canonical hardware table
 
 __all__ = ["HW", "HBM_GB", "load", "render", "rows", "render_lda",
@@ -167,6 +168,7 @@ def rows():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for mesh in ("single", "multi"):
         print(f"\n## Roofline — {mesh} pod\n")
         for line in render(mesh=mesh):

@@ -26,6 +26,8 @@ def main() -> None:
     ap.add_argument("--corpus", default="small")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig1_convergence, fig2_minibatch, fig5_delays,
                             kernel_bench, roofline, table2_divi)
     sections = {

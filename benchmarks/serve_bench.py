@@ -40,6 +40,7 @@ import time
 import numpy as np
 
 from repro.core import LDAConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 # ---------------------------------------------------------------------------
 # model constants (documented in docs/streaming.md §benchmark)
@@ -190,6 +191,7 @@ def serve_report(json_path: str | None = None, *, dryrun: bool = False
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_serve.json",
                     help="where to write the serving record")

@@ -39,6 +39,8 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 # ---------------------------------------------------------------------------
 # bench constants (documented in docs/serving.md §benchmark)
 # ---------------------------------------------------------------------------
@@ -223,6 +225,7 @@ def service_report(json_path=None, *, dryrun: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_service.json",
                     help="where to write the service record")

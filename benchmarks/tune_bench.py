@@ -35,6 +35,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 from repro.tune.search import (TuneShape, measurement_available,  # noqa: E402
                                tune_shape)
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.tune.store import current_device_kind, policy_to_dict  # noqa: E402
 
 PANEL = {
@@ -111,6 +112,7 @@ def tune_report(json_path=None, *, dryrun: bool = False, seed: int = 0,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_tune.json",
                     help="where to write the tuned-vs-default records")

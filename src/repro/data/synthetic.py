@@ -62,6 +62,12 @@ def make_corpus(spec: SyntheticSpec, *, split: str = "train",
     import zlib
     topic_rng = np.random.default_rng(zlib.crc32(spec.name.encode()))
     phi = topic_rng.dirichlet([spec.beta] * spec.vocab_size, spec.num_topics)
+    # each topic's normalised CDF, built once: exactly the table
+    # ``rng.choice(V, p=phi[k])`` rebuilds per call (O(V) each), so the
+    # inverse-CDF draws below consume the rng identically and give the
+    # same words
+    cdf = np.cumsum(phi, axis=1)
+    cdf /= cdf[:, -1:]
     docs = []
     lengths = np.maximum(rng.poisson(spec.mean_len, n_docs), 4)
     for n in lengths:
@@ -70,6 +76,6 @@ def make_corpus(spec: SyntheticSpec, *, split: str = "train",
         # sample words per unique topic in bulk (much faster than per-word)
         doc = np.empty(n, np.int64)
         for k, cnt in zip(*np.unique(z, return_counts=True)):
-            doc[z == k] = rng.choice(spec.vocab_size, size=cnt, p=phi[k])
+            doc[z == k] = cdf[k].searchsorted(rng.random(cnt), side="right")
         docs.append(doc)
     return corpus_from_docs(docs, spec.vocab_size)

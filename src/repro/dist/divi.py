@@ -32,11 +32,6 @@ import math
 from functools import partial
 
 import jax
-
-try:                                      # jax >= 0.6: out of experimental
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.math import exp_dirichlet_expectation
@@ -122,6 +117,6 @@ def make_divi_round(cfg: LDAConfig, dcfg: DIVIConfig, mesh,
              idx.swapaxes(0, 1), delay.swapaxes(0, 1)))
         return state, WorkerShard(memo=memo)
 
-    fn = shard_map(round_body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(round_body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn, donate_argnums=(0, 1))

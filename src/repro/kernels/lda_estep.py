@@ -50,10 +50,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-30  # fp32-safe (1e-100 underflows to 0)
+# full-fp32 MXU contractions: Mosaic's default f32 matmul rounds its
+# operands to bf16, and the γ fixed point then never settles under the
+# mean-|Δγ| tolerance (measured on a v5e: 100 of 100 sweeps, γ off by 0.7)
+_F32 = jax.lax.Precision.HIGHEST
+# fp32 contractions keep bf16 splits of their operands in VMEM, which takes
+# the Arxiv-width tiles past Mosaic's default 16 MiB scoped limit (up to
+# ~22 MiB, tests/test_tpu_compile.py); a v5e core has 128 MiB of VMEM
+_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=48 * 1024 * 1024)
 
 
 def _default_interpret(interpret):
-    return jax.default_backend() != "tpu" if interpret is None else interpret
+    """Interpret mode on the CPU, Mosaic on a TPU; any other backend raises.
+
+    The kernels lower only through Mosaic, so a GPU (or any non-TPU
+    accelerator) would otherwise run the Pallas interpreter silently.
+    """
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the LDA Pallas kernels run on a TPU (Mosaic) or in "
+                       f"interpret mode on the CPU, not on {backend!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -123,9 +144,11 @@ def _fixed_point_kernel(alpha0: float, tol: float, k_real: int,
         et = et_s[...]                                 # (bB, K)
         eb = eb_ref[...].astype(jnp.float32)           # (bV, K)
         p = jax.lax.dot_general(et, eb, (((1,), (1,)), ((), ())),
+                                precision=_F32,
                                 preferred_element_type=jnp.float32) + _EPS
         r = c_ref[...].astype(jnp.float32) / p         # (bB, bV)
         acc_s[...] += jax.lax.dot(r, eb,
+                                  precision=_F32,
                                   preferred_element_type=jnp.float32)
 
     @pl.when(live & (j == num_v - 1))
@@ -147,7 +170,7 @@ def _fixed_point_kernel(alpha0: float, tol: float, k_real: int,
         g = gamma_s[...]
         gamma_ref[...] = g
         et_ref[...] = _exp_elog_theta(g, k_real)
-        iters_ref[0, 0] = flags[1]
+        iters_ref[...] = jnp.full(iters_ref.shape, flags[1], jnp.int32)
 
 
 def estep_fixed_point(c: jax.Array, eb: jax.Array, gamma0: jax.Array,
@@ -183,12 +206,15 @@ def estep_fixed_point(c: jax.Array, eb: jax.Array, gamma0: jax.Array,
         out_specs=[
             pl.BlockSpec((block_b, k), lambda i, t, j: (i, 0)),
             pl.BlockSpec((block_b, k), lambda i, t, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, t, j: (i, 0)),
+            # one (1, 1) block per B-tile: with the tile axis squeezed the
+            # block equals the array's last two dims, which Mosaic's (8, 128)
+            # tiling rule accepts (a (1, 1) block of an (nb, 1) array is not)
+            pl.BlockSpec((None, 1, 1), lambda i, t, j: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, k), jnp.float32),
             jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, 1), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_b, k), jnp.float32),
@@ -196,9 +222,10 @@ def estep_fixed_point(c: jax.Array, eb: jax.Array, gamma0: jax.Array,
             pltpu.VMEM((block_b, k), jnp.float32),
             pltpu.SMEM((2,), jnp.int32),
         ],
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(c, eb, gamma0)
-    return gamma, et, iters
+    return gamma, et, iters.reshape(nb, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -250,15 +277,17 @@ def _segment_scatter_kernel(has_old: bool, *refs):
             sold_ref[...] = jnp.zeros_like(sold_ref)
 
     bv = snew_ref.shape[0]
-    tb = ids_ref.shape[1]
+    tb = ids_ref.shape[-1]
     rows = j * bv + jax.lax.broadcasted_iota(jnp.int32, (bv, tb), 0)
     # count-scaled segment selector: (bV, T) — doubles as the MXU scatter
     # operand, so cnt·π never materialises as a separate row array
     weights = jnp.where(rows == ids_ref[...], cnts_ref[...], 0.0)
     snew_ref[...] += jax.lax.dot(weights, wnew_ref[...],
+                                 precision=_F32,
                                  preferred_element_type=jnp.float32)
     if has_old:
         sold_ref[...] += jax.lax.dot(weights, wold_ref[...],
+                                     precision=_F32,
                                      preferred_element_type=jnp.float32)
 
 
@@ -371,6 +400,7 @@ def memo_delta(token_ids: jax.Array, counts: jax.Array, eb_tok: jax.Array,
         ],
         out_specs=pl.BlockSpec((bb, bl, k), lambda i, li: (i, li, 0)),
         out_shape=jax.ShapeDtypeStruct((b, lp, k), jnp.float32),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(cnts_p, ebt_p, etheta)
 
@@ -389,15 +419,17 @@ def memo_delta(token_ids: jax.Array, counts: jax.Array, eb_tok: jax.Array,
         pad = ((0, rows_p - rows),) + ((0, 0),) * (flat.ndim - 1)
         return jnp.pad(flat, pad)
 
-    ids2 = _flat_rows(ids_p, None).reshape(nt, tb)
-    cnts2 = _flat_rows(cnts_p, None).reshape(nt, tb)
+    ids2 = _flat_rows(ids_p, None).reshape(nt, 1, tb)
+    cnts2 = _flat_rows(cnts_p, None).reshape(nt, 1, tb)
     wnew = _flat_rows(pi_pad, k)
     inputs = [ids2, cnts2, wnew]
     if has_old:
         inputs.append(_flat_rows(_pad_l(old_pi), k))
 
     vp = _round_up(vocab_size, vc)
-    row_spec = pl.BlockSpec((1, tb), lambda j, t: (t, 0))
+    # ids/counts ride as (nt, 1, tb): the squeezed row-tile axis makes each
+    # (1, tb) block span the last two dims, as Mosaic's tiling rule needs
+    row_spec = pl.BlockSpec((None, 1, tb), lambda j, t: (t, 0, 0))
     w_spec = pl.BlockSpec((tb, k), lambda j, t: (t, 0))
     acc_spec = pl.BlockSpec((vc, k), lambda j, t: (j, 0))
     n_out = 2 if has_old else 1
@@ -407,6 +439,7 @@ def memo_delta(token_ids: jax.Array, counts: jax.Array, eb_tok: jax.Array,
         in_specs=[row_spec, row_spec, w_spec] + [w_spec] * (n_out - 1),
         out_specs=[acc_spec] * n_out,
         out_shape=[jax.ShapeDtypeStruct((vp, k), jnp.float32)] * n_out,
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(*inputs)
 
@@ -465,10 +498,12 @@ def _csr_fixed_point_kernel(alpha0: float, tol: float, k_real: int,
         # every (doc, token) pair on the MXU and masked down, which keeps
         # the kernel gather-free (the trade for zero padding)
         p = jax.lax.dot_general(et, ebt, (((1,), (1,)), ((), ())),
+                                precision=_F32,
                                 preferred_element_type=jnp.float32)
         pnorm = jnp.where(sel, p, 0.0).sum(0, keepdims=True) + _EPS
         w = jnp.where(sel, cnts / pnorm, 0.0)          # (Bp, bT)
         acc_s[...] += jax.lax.dot(w, ebt,
+                                  precision=_F32,
                                   preferred_element_type=jnp.float32)
 
     @pl.when(live & (j == num_j - 1))
@@ -488,7 +523,7 @@ def _csr_fixed_point_kernel(alpha0: float, tol: float, k_real: int,
         g = gamma_s[...]
         gamma_ref[...] = g
         et_ref[...] = _exp_elog_theta(g, k_real)
-        iters_ref[0, 0] = flags[1]
+        iters_ref[...] = jnp.full(iters_ref.shape, flags[1], jnp.int32)
 
 
 def estep_fixed_point_csr(cnts: jax.Array, segs: jax.Array,
@@ -521,16 +556,16 @@ def estep_fixed_point_csr(cnts: jax.Array, segs: jax.Array,
         segs = jnp.pad(segs, (0, tp - t))
         eb_tok = jnp.pad(eb_tok, ((0, tp - t), (0, 0)))
     nj = tp // block_t
-    cnts2 = cnts.reshape(nj, block_t)
-    segs2 = segs.reshape(nj, block_t)
+    cnts2 = cnts.reshape(nj, 1, block_t)
+    segs2 = segs.reshape(nj, 1, block_t)
     grid = (max(int(max_iters), 1), nj)
     gamma, et, iters = pl.pallas_call(
         functools.partial(_csr_fixed_point_kernel, alpha0, tol, k_real,
                           b_real, grid[0], nj),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_t), lambda t, j: (j, 0)),
-            pl.BlockSpec((1, block_t), lambda t, j: (j, 0)),
+            pl.BlockSpec((None, 1, block_t), lambda t, j: (j, 0, 0)),
+            pl.BlockSpec((None, 1, block_t), lambda t, j: (j, 0, 0)),
             pl.BlockSpec((block_t, k), lambda t, j: (j, 0)),
             pl.BlockSpec((b, k), lambda t, j: (0, 0)),
         ],
@@ -550,6 +585,7 @@ def estep_fixed_point_csr(cnts: jax.Array, segs: jax.Array,
             pltpu.VMEM((b, k), jnp.float32),
             pltpu.SMEM((2,), jnp.int32),
         ],
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(cnts2, segs2, eb_tok, gamma0)
     return gamma, et, iters
@@ -571,10 +607,11 @@ def _csr_token_pi_kernel(quantize: bool, cnts_ref, segs_ref, ebtok_ref,
     rows = jax.lax.broadcasted_iota(jnp.int32, (bp, bt), 0)
     sel = rows == segs
     p = jax.lax.dot_general(et, ebt, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+                            precision=_F32, preferred_element_type=jnp.float32)
     pnorm = jnp.where(sel, p, 0.0).sum(0, keepdims=True) + _EPS
     selw = jnp.where(sel & (cnts > 0), 1.0 / pnorm, 0.0)
     pi = jax.lax.dot_general(selw, et, (((0,), (0,)), ((), ())),
+                             precision=_F32,
                              preferred_element_type=jnp.float32) * ebt
     if quantize:
         # round through the memo wire dtype BEFORE the scatter, so ⟨m_vk⟩
@@ -620,15 +657,16 @@ def memo_delta_csr(token_ids: jax.Array, counts: jax.Array,
         functools.partial(_csr_token_pi_kernel, quantize),
         grid=(nj,),
         in_specs=[
-            pl.BlockSpec((1, bt), lambda j: (j, 0)),
-            pl.BlockSpec((1, bt), lambda j: (j, 0)),
+            pl.BlockSpec((None, 1, bt), lambda j: (j, 0, 0)),
+            pl.BlockSpec((None, 1, bt), lambda j: (j, 0, 0)),
             pl.BlockSpec((bt, k), lambda j: (j, 0)),
             pl.BlockSpec(etheta.shape, lambda j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bt, k), lambda j: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((tp, k), jnp.float32),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(cnts_p.reshape(nj, bt), segs_p.reshape(nj, bt), ebt_p, etheta)
+    )(cnts_p.reshape(nj, 1, bt), segs_p.reshape(nj, 1, bt), ebt_p, etheta)
 
     # -- kernel 2: the SAME segment-sum scatter as the padded path ------
     vc, tb = segment_scatter_blocks(k, vocab_size, has_old,
@@ -643,14 +681,14 @@ def memo_delta_csr(token_ids: jax.Array, counts: jax.Array,
         return jnp.pad(x, pad)
 
     nt = rows_p // tb
-    ids2 = _scatter_rows(ids_p).reshape(nt, tb)
-    cnts2 = _scatter_rows(cnts_p).reshape(nt, tb)
+    ids2 = _scatter_rows(ids_p).reshape(nt, 1, tb)
+    cnts2 = _scatter_rows(cnts_p).reshape(nt, 1, tb)
     inputs = [ids2, cnts2, _scatter_rows(pi_pad)]
     if has_old:
         inputs.append(_scatter_rows(_pad_t(old_pi)))
 
     vp = _round_up(vocab_size, vc)
-    row_spec = pl.BlockSpec((1, tb), lambda j, t: (t, 0))
+    row_spec = pl.BlockSpec((None, 1, tb), lambda j, t: (t, 0, 0))
     w_spec = pl.BlockSpec((tb, k), lambda j, t: (t, 0))
     acc_spec = pl.BlockSpec((vc, k), lambda j, t: (j, 0))
     n_out = 2 if has_old else 1
@@ -660,6 +698,7 @@ def memo_delta_csr(token_ids: jax.Array, counts: jax.Array,
         in_specs=[row_spec, row_spec, w_spec] + [w_spec] * (n_out - 1),
         out_specs=[acc_spec] * n_out,
         out_shape=[jax.ShapeDtypeStruct((vp, k), jnp.float32)] * n_out,
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(*inputs)
 

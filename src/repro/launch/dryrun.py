@@ -190,8 +190,6 @@ def run_pair(arch: str, shape_name: str, mesh_kind: str,
             "alias_gb": mem.alias_size_in_bytes / 1e9,
         }
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # jax<=0.4.x returns [dict]
-            ca = ca[0] if ca else {}
         out["cost_analysis"] = {
             "flops_once": float(ca.get("flops", 0.0)),
             "bytes_once": float(ca.get("bytes accessed", 0.0)),

@@ -223,6 +223,8 @@ def main():
         with open(args.out, "a") as f:
             for res in results:
                 f.write(json.dumps(res) + "\n")
+    if not all(res["ok"] for res in results):
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

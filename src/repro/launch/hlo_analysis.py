@@ -28,6 +28,7 @@ from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
 import jax
+from jax.extend import core as jax_core
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -237,15 +238,15 @@ def pallas_call_sites(fn, *args, **kwargs) -> Dict[str, int]:
 
     def sub_jaxprs(eqn):
         for v in eqn.params.values():
-            if isinstance(v, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+            if isinstance(v, (jax_core.Jaxpr, jax_core.ClosedJaxpr)):
                 yield v
             elif isinstance(v, (tuple, list)):
                 for x in v:
-                    if isinstance(x, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+                    if isinstance(x, (jax_core.Jaxpr, jax_core.ClosedJaxpr)):
                         yield x
 
     def walk(jx, in_loop):
-        if isinstance(jx, jax.core.ClosedJaxpr):
+        if isinstance(jx, jax_core.ClosedJaxpr):
             jx = jx.jaxpr
         for eqn in jx.eqns:
             name = eqn.primitive.name
@@ -282,16 +283,16 @@ def dense_vocab_cubes(fn, vocab_size: int, *args, **kwargs) -> int:
 
     def sub_jaxprs(eqn):
         for v in eqn.params.values():
-            if isinstance(v, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+            if isinstance(v, (jax_core.Jaxpr, jax_core.ClosedJaxpr)):
                 yield v
             elif isinstance(v, (tuple, list)):
                 for x in v:
-                    if isinstance(x, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+                    if isinstance(x, (jax_core.Jaxpr, jax_core.ClosedJaxpr)):
                         yield x
 
     def walk(jx):
         nonlocal count
-        if isinstance(jx, jax.core.ClosedJaxpr):
+        if isinstance(jx, jax_core.ClosedJaxpr):
             jx = jx.jaxpr
         for eqn in jx.eqns:
             for ov in eqn.outvars:

@@ -21,15 +21,10 @@ def make_host_mesh(data: int = 1, model: int = 1):
 
 
 def make_abstract_mesh(shape, axes):
-    """Version-compatible ``jax.sharding.AbstractMesh``.
+    """``jax.sharding.AbstractMesh`` of ``shape`` over ``axes``.
 
-    jax >= 0.5 takes ``(axis_sizes, axis_names)``; jax 0.4.x takes a single
-    ``shape_tuple`` of ``(name, size)`` pairs. AbstractMesh carries only
-    shape/axis metadata, so constructing it never touches device state.
+    AbstractMesh carries only shape/axis metadata, so constructing it never
+    touches device state.
     """
     from jax.sharding import AbstractMesh
-    shape, axes = tuple(shape), tuple(axes)
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))

@@ -84,6 +84,8 @@ def run_serve_dryrun(batch: int = 256, widths=ARXIV_WIDTHS,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--ckpt", default=None,
                     help="LDA checkpoint (manifest dir or legacy .npz); "
@@ -157,6 +159,8 @@ def main() -> None:
         if args.out:
             with open(args.out, "a") as f:
                 f.write(json.dumps(res) + "\n")
+        if not res["ok"]:
+            raise SystemExit(1)
         return
 
     from repro.data import PAPER_CORPORA, make_corpus

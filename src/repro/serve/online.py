@@ -82,6 +82,7 @@ class OnlineLearner:
         self.armed_observations = 0
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
+        self._error: Optional[BaseException] = None
 
     # -- intake (called from the serving loop) ---------------------------
     def observe(self, docs) -> int:
@@ -161,14 +162,22 @@ class OnlineLearner:
 
     # -- background cadence ----------------------------------------------
     def start(self) -> "OnlineLearner":
-        """Run ``update_once`` on the background cadence until ``stop``."""
+        """Run ``update_once`` on the background cadence until ``stop``.
+
+        An exception raised by ``update_once`` ends the thread; it is kept
+        and re-raised by ``stop``, so a failed learner never goes unseen.
+        """
         if self._thread is not None:
             raise ValueError("learner already started")
         self._stop.clear()
+        self._error = None
 
         def loop():
-            while not self._stop.wait(self.cadence_s):
-                self.update_once()
+            try:
+                while not self._stop.wait(self.cadence_s):
+                    self.update_once()
+            except BaseException as e:  # noqa: BLE001 — re-raised by stop()
+                self._error = e
 
         self._thread = threading.Thread(target=loop, name="online-learner",
                                         daemon=True)
@@ -176,12 +185,16 @@ class OnlineLearner:
         return self
 
     def stop(self) -> None:
-        """Stop the background thread (idempotent; joins it)."""
+        """Stop the background thread (idempotent; joins it) and re-raise
+        any exception that ended it."""
         if self._thread is None:
             return
         self._stop.set()
         self._thread.join()
         self._thread = None
+        err, self._error = self._error, None
+        if err is not None:
+            raise err
 
     def __enter__(self) -> "OnlineLearner":
         return self.start()

@@ -431,6 +431,25 @@ def test_online_learner_drain_arms_watchdog(tiny_lda):
     assert all(r["delta"] is None or r["delta"] >= -wd.tol for r in armed)
 
 
+def test_online_learner_background_failure_raises_from_stop(tiny_lda,
+                                                           monkeypatch):
+    """An exception in the background thread is not lost: ``stop`` raises it."""
+    inf = tiny_lda.inferencer(batch_size=8)
+    learner = OnlineLearner(tiny_lda.cfg, SnapshotStore(inf),
+                            cadence_s=0.001, seed=0)
+
+    def boom(**_):
+        raise RuntimeError("update failed")
+
+    monkeypatch.setattr(learner, "update_once", boom)
+    learner.start()
+    learner._thread.join(timeout=10)
+    assert not learner._thread.is_alive()
+    with pytest.raises(RuntimeError, match="update failed"):
+        learner.stop()
+    learner.stop()                                # the error is raised once
+
+
 # ---------------------------------------------------------------------------
 # the serving loop end to end
 # ---------------------------------------------------------------------------

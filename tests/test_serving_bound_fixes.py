@@ -134,7 +134,9 @@ def test_serve_lda_latency_report(tmp_path, monkeypatch, capsys):
     """The launcher end-to-end on the tiny corpus: its jit-cache line and
     JSON record must use the corrected cache_info fields."""
     import sys
-    from repro.launch import serve_lda
+    from repro.launch import compile_cache, serve_lda
+    # keep the test process off the checkout's persistent compile cache
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
     out = tmp_path / "serve.jsonl"
     monkeypatch.setattr(sys, "argv", [
         "serve_lda", "--corpus", "tiny", "--requests", "3", "--batch", "8",

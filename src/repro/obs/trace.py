@@ -16,6 +16,23 @@ the D-IVI round driver) into an in-memory buffer of plain dicts:
   (measuring dispatch is the right thing inside the double-buffered
   serving loop, where a sync would serialize the overlap being measured).
 * **events** — ``event(name, **attrs)``: zero-duration markers.
+* **attrs known at the end** — ``end(token, **attrs)`` adds attrs that
+  only the span's own work could tell (``shed``, ``batches``).
+* **stall spans** — while at least one live recorder exists, two hooks,
+  installed once per process, forward to every live recorder: a
+  ``gc.callbacks`` hook records ``py/gc`` around each garbage collection
+  (attrs ``generation``, ``collected``) on the thread that collected, and
+  a ``jax.monitoring`` duration listener records ``jax/compile`` for each
+  backend compile (``event="backend_compile"``, which holds any
+  compile-cache read) and each compile-cache read
+  (``event="cache_load"``, nested one level deeper), after the fact:
+  start = now − duration. Both nest inside whatever span was open, so a
+  stall is charged to them and not to the span it interrupted.
+* **profiler annotations** — while the JAX profiler is tracing, every
+  span but ``jax/compile`` also opens a ``jax.profiler.TraceAnnotation``
+  of the same name and attrs, so the spans stand on the profile's host
+  plane, on the profiler's clock, beside the device ops. With the
+  profiler off this costs one ``TraceMe.is_enabled()`` call per span.
 
 Export is JSONL (one record per line, ``dump_jsonl``; schema below) plus
 a converter to the Chrome trace-event format, loadable in
@@ -46,16 +63,28 @@ on the traced quickstart smoke.
 """
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
+import weakref
 from typing import Dict, Iterable, List, Optional, Tuple
 
 TRACE_SCHEMA = "repro.obs.trace"
 TRACE_SCHEMA_VERSION = 1
 
-# (name, attrs, depth, start_ns) — what ``begin`` hands to ``end``
-SpanToken = Tuple[str, dict, int, int]
+# (name, attrs, depth, start_ns, profiler annotation or None) — what
+# ``begin`` hands to ``end``
+SpanToken = Tuple[str, dict, int, int, object]
+
+GC_SPAN = "py/gc"
+COMPILE_SPAN = "jax/compile"
+# JAX's duration events → the ``event`` attr of a ``jax/compile`` span. A
+# backend compile event covers the compile-cache read (if any) made for it.
+COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
 
 
 class _NullSpan:
@@ -92,7 +121,7 @@ class NullSpanRecorder:
     def begin(self, name: str, **attrs) -> None:
         return None
 
-    def end(self, token, sync=None) -> None:
+    def end(self, token, sync=None, **attrs) -> None:
         pass
 
     def event(self, name: str, **attrs) -> None:
@@ -108,6 +137,50 @@ class NullSpanRecorder:
 
 
 NULL_TRACE = NullSpanRecorder()
+
+
+# ---------------------------------------------------------------------------
+# process-wide stall hooks, forwarding to the live recorders
+# ---------------------------------------------------------------------------
+
+_LIVE: "weakref.WeakSet[SpanRecorder]" = weakref.WeakSet()
+_INSTALL_LOCK = threading.Lock()
+# jax.profiler.TraceAnnotation, bound when the hooks are installed
+_Annotation = None
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    for rec in _LIVE:
+        if phase == "start":
+            rec._tls.gc = rec.begin(GC_SPAN, generation=info["generation"])
+        else:
+            tok = getattr(rec._tls, "gc", None)
+            if tok is not None:
+                rec._tls.gc = None
+                rec.end(tok, collected=info["collected"])
+
+
+def _compile_hook(event: str, duration_secs: float, **_) -> None:
+    kind = COMPILE_EVENTS.get(event)
+    if kind is None:
+        return
+    for rec in _LIVE:
+        rec._ended_span(COMPILE_SPAN, duration_secs,
+                        inner=int(kind == "cache_load"), event=kind)
+
+
+def _watch_stalls(rec: "SpanRecorder") -> None:
+    """Forward the stall hooks to ``rec``; install them on first use."""
+    global _Annotation
+    with _INSTALL_LOCK:
+        if _Annotation is None:
+            import jax
+
+            gc.callbacks.append(_gc_hook)
+            jax.monitoring.register_event_duration_secs_listener(
+                _compile_hook)
+            _Annotation = jax.profiler.TraceAnnotation
+        _LIVE.add(rec)
 
 
 class _Span:
@@ -152,6 +225,7 @@ class SpanRecorder:
         self._tls = threading.local()
         self._tids: Dict[int, int] = {}
         self._lock = threading.Lock()
+        _watch_stalls(self)
 
     # -- recording -------------------------------------------------------
     def _tid(self) -> int:
@@ -163,27 +237,54 @@ class SpanRecorder:
         return tid
 
     def begin(self, name: str, **attrs) -> SpanToken:
-        """Open a span; pass the returned token to ``end``."""
+        """Open a span (and its profiler annotation, while the profiler
+        traces); pass the returned token to ``end``."""
+        t0 = time.perf_counter_ns()
         depth = getattr(self._tls, "depth", 0)
         self._tls.depth = depth + 1
-        return (name, attrs, depth, time.perf_counter_ns())
+        ann = None
+        if _Annotation.is_enabled():
+            ann = _Annotation(name, **attrs)
+            ann.__enter__()
+        return (name, attrs, depth, t0, ann)
 
-    def end(self, token: SpanToken, sync=None) -> None:
-        """Close a span. With ``device_sync`` and a ``sync`` array/pytree,
-        blocks until the device work is done before timestamping — the
-        optional ``block_until_ready`` sync point."""
+    def end(self, token: SpanToken, sync=None, **attrs) -> None:
+        """Close a span, adding ``attrs`` to those given to ``begin``.
+        With ``device_sync`` and a ``sync`` array/pytree, blocks until the
+        device work is done before timestamping — the optional
+        ``block_until_ready`` sync point."""
         if sync is not None and self.device_sync:
             import jax
 
             jax.block_until_ready(sync)
-        t1 = time.perf_counter_ns()
-        name, attrs, depth, t0 = token
+        name, span_attrs, depth, t0, ann = token
+        if ann is not None:
+            if attrs:
+                ann.set_metadata(**attrs)
+            ann.__exit__(None, None, None)
+        if attrs:
+            span_attrs.update(attrs)
         self._tls.depth = depth
+        tid = self._tid()
+        t1 = time.perf_counter_ns()
         self._records.append({
             "type": "span", "name": name,
             "ts_us": (t0 - self._t0) / 1e3,
             "dur_us": (t1 - t0) / 1e3,
-            "tid": self._tid(), "depth": depth, "attrs": attrs,
+            "tid": tid, "depth": depth, "attrs": span_attrs,
+        })
+
+    def _ended_span(self, name: str, dur_s: float, *, inner: int = 0,
+                    **attrs) -> None:
+        """Record a span that ends now and lasted ``dur_s``, nested
+        ``inner`` levels below the spans open on this thread (no profiler
+        annotation: it is recorded after the fact)."""
+        t1 = time.perf_counter_ns()
+        self._records.append({
+            "type": "span", "name": name,
+            "ts_us": (t1 - self._t0) / 1e3 - dur_s * 1e6,
+            "dur_us": dur_s * 1e6, "tid": self._tid(),
+            "depth": getattr(self._tls, "depth", 0) + inner, "attrs": attrs,
         })
 
     def span(self, name: str, **attrs) -> _Span:

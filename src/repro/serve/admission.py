@@ -56,7 +56,11 @@ class Response:
     ``status`` is ``"ok"`` (γ present, ``model_version`` identifies the
     snapshot that served it) or ``"shed"`` (refused at admission; γ and
     version are None). ``latency_s`` is completion − scheduled arrival —
-    open-loop latency, queueing included.
+    open-loop latency, queueing included. ``admit_s`` (when the service
+    offered the request to admission) and ``start_s`` (when the batch
+    that served it started) split the wait into intake (arrival → admit)
+    and batch formation (admit → start); both are on the service clock
+    and None for a shed request.
     """
 
     rid: int
@@ -65,6 +69,8 @@ class Response:
     model_version: Optional[int]
     arrival_s: float
     done_s: float
+    admit_s: Optional[float] = None
+    start_s: Optional[float] = None
 
     @property
     def latency_s(self) -> float:
@@ -139,16 +145,18 @@ class AdmissionController:
         batch = self.packer.add(pos, req.ids, req.cnts)
         return True, batch
 
-    def take(self, rows: np.ndarray, now: float) -> List[Request]:
-        """Pop the requests of an emitted batch, in row order — the
-        service maps γ rows back to requests through this."""
+    def take(self, rows: np.ndarray,
+             now: float) -> List[Tuple[Request, float]]:
+        """Pop the requests of an emitted batch with their admit times,
+        in row order — the service maps γ rows back to requests through
+        this."""
         out = []
         for pos in np.asarray(rows, np.int64):
             req, admit_t = self._pending.pop(int(pos))
             if self.metrics is not None:
                 self.metrics.observe("admit.queue_wait_ms",
                                      (now - admit_t) * 1e3)
-            out.append(req)
+            out.append((req, admit_t))
         return out
 
     # -- flush policy ----------------------------------------------------
@@ -161,15 +169,17 @@ class AdmissionController:
         return min((r.deadline_s for r, _ in self._pending.values()),
                    default=math.inf)
 
-    def poll(self, now: float) -> List:
-        """Emit every open bucket if a flush trigger is due at ``now``;
-        an empty window (nothing pending) never flushes."""
+    def due(self, now: float) -> bool:
+        """Whether a flush trigger fires at ``now``; an empty window
+        (nothing pending) is never due."""
         if not self._pending:
-            return []
-        oldest = self._oldest_admit()
-        due = (now - oldest >= self.flush_timeout_s
-               or self._min_deadline() - now <= self.deadline_headroom_s)
-        if not due:
+            return False
+        return (now - self._oldest_admit() >= self.flush_timeout_s
+                or self._min_deadline() - now <= self.deadline_headroom_s)
+
+    def poll(self, now: float) -> List:
+        """Emit every open bucket if a flush trigger is due at ``now``."""
+        if not self.due(now):
             return []
         batches = self.packer.flush()
         if batches and self.metrics is not None:

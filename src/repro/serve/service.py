@@ -17,6 +17,16 @@ included, the honest client-side number. Batches run through
 ``TopicInferencer.posterior_packed`` and block per batch, so the latency
 histogram measures real device completion, not dispatch.
 
+With a live ``repro.obs`` bundle the loop's top-level spans tile it:
+``serve/admit`` per offered request (the offer, the packer's ``add`` and
+the flush check after it; attr ``lag_ms``: offer − scheduled arrival,
+the intake wait; ``shed`` when refused), ``serve/flush`` around a poll or
+close that emits batches (attr ``batches``), ``serve/request_batch`` (the
+E-step, blocked on γ) then ``serve/respond`` (the batch's requests out of
+admission, γ to the host, the responses, the latency accounting, the
+learner) per served batch, and ``serve/wait`` around each sleep (attr
+``until``: ``arrival`` or ``flush``). None of them syncs the device.
+
 Every OK response records the ``model_version`` of the snapshot that
 served it; under an ``OnlineLearner`` the version advances mid-stream
 while in-flight batches complete on the snapshot they started with
@@ -69,9 +79,10 @@ class ServingService:
         served document is fed to it (non-blocking append; training and
         λ publication happen on the learner's own cadence/thread).
       telemetry: ``repro.obs`` bundle. The service ALWAYS keeps a
-        metrics registry (latency accounting is the product here, not
-        optional observability): the bundle's when enabled, a private one
-        otherwise.
+        metrics registry for ``serve.latency_ms`` (latency accounting is
+        the product here, not optional observability): the bundle's when
+        enabled, a private one otherwise. The ``admit.*``, ``pack.*`` and
+        ``serve.shed`` counters go to a live bundle only.
       clock/sleep: injectable time sources (tests).
     """
 
@@ -91,7 +102,7 @@ class ServingService:
             flush_timeout_s=self.config.flush_timeout_s,
             shed_margin_s=self.config.shed_margin_s,
             deadline_headroom_s=self.config.deadline_headroom_s,
-            metrics=self.metrics)
+            metrics=self.tel.metrics if self.tel.enabled else None)
         self.responses: List[Response] = []
         self._t0: Optional[float] = None
         self._last_done = 0.0
@@ -112,6 +123,7 @@ class ServingService:
         """
         if self._t0 is None:
             self._t0 = self._clock()
+        tel = self.tel
         out_start = len(self.responses)
         for req in requests:
             # sleep toward the arrival, waking for due partial flushes
@@ -122,52 +134,84 @@ class ServingService:
                 due = self.admission.next_due(now)
                 if due is not None and due < req.arrival_s:
                     if due > now:
-                        self._sleep(due - now)
+                        self._wait(due - now, "flush")
                     self._poll_flushes()
                 else:
-                    self._sleep(req.arrival_s - now)
-            now = self._now()
+                    self._wait(req.arrival_s - now, "arrival")
+            sp = tel.trace.begin("serve/admit",
+                                 lag_ms=(now - req.arrival_s) * 1e3) \
+                if tel.enabled else None
             admitted, batch = self.admission.offer(req, now)
             if not admitted:
                 self.responses.append(Response(
                     rid=req.rid, status="shed", gamma=None,
                     model_version=None, arrival_s=req.arrival_s,
                     done_s=now))
-                self.metrics.inc("serve.shed")
+            flush = batch is None and self.admission.due(now)
+            if sp is not None:
+                if admitted:
+                    tel.trace.end(sp)
+                else:
+                    tel.trace.end(sp, shed=True)
+                    tel.metrics.inc("serve.shed")
             if batch is not None:
                 self._serve_batch(batch)
-            self._poll_flushes()
-        for batch in self.admission.close(self._now()):
-            self._serve_batch(batch)
+                now = self._now()
+                flush = self.admission.due(now)
+            if flush:
+                self._flush(self.admission.poll, now)
+        self._flush(self.admission.close, self._now())
         return self.responses[out_start:]
 
+    def _wait(self, seconds: float, until: str) -> None:
+        tel = self.tel
+        sp = tel.trace.begin("serve/wait", until=until) \
+            if tel.enabled else None
+        self._sleep(seconds)
+        if sp is not None:
+            tel.trace.end(sp)
+
     def _poll_flushes(self) -> None:
-        for batch in self.admission.poll(self._now()):
+        now = self._now()
+        if self.admission.due(now):
+            self._flush(self.admission.poll, now)
+
+    def _flush(self, emit, now: float) -> None:
+        """Serve the batches ``emit(now)`` (the admission's ``poll`` or
+        ``close``) hands out, under one ``serve/flush`` span."""
+        tel = self.tel
+        sp = tel.trace.begin("serve/flush") if tel.enabled else None
+        batches = emit(now)
+        for batch in batches:
             self._serve_batch(batch)
+        if sp is not None:
+            tel.trace.end(sp, batches=len(batches))
 
     def _serve_batch(self, batch) -> None:
         tel = self.tel
-        reqs = self.admission.take(batch.rows, self._now())
+        start = self._now()
         sp = tel.trace.begin("serve/request_batch",
-                             docs=len(reqs)) if tel.enabled else None
+                             docs=len(batch.rows)) if tel.enabled else None
         _, gamma, n, version = self.inf.posterior_packed(batch)
         gamma.block_until_ready()          # honest completion time
         if sp is not None:
             tel.trace.end(sp)
+            sp = tel.trace.begin("serve/respond", docs=n)
         done = self._now()
         self._last_done = max(self._last_done, done)
+        taken = self.admission.take(batch.rows, start)
         g = np.asarray(gamma[:n])
-        for i, req in enumerate(reqs):
+        for i, (req, admit_s) in enumerate(taken):
             self.responses.append(Response(
                 rid=req.rid, status="ok", gamma=g[i],
                 model_version=version, arrival_s=req.arrival_s,
-                done_s=done))
+                done_s=done, admit_s=admit_s, start_s=start))
             self.metrics.observe("serve.latency_ms",
                                  (done - req.arrival_s) * 1e3)
-        self.metrics.inc("serve.batches")
-        self.metrics.inc("serve.docs", len(reqs))
         if self.learner is not None:
-            self.learner.observe([(r.ids, r.cnts) for r in reqs])
+            self.learner.observe([(r.ids, r.cnts) for r, _ in taken])
+        if sp is not None:
+            tel.trace.end(sp)
 
     # -- reporting -------------------------------------------------------
     def slo_report(self) -> dict:

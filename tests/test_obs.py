@@ -1,7 +1,12 @@
 """repro.obs: tracing, metrics, watchdog, and the no-op-when-off contract."""
 import dataclasses
+import gc
 import json
+import os
+import subprocess
+import sys
 import warnings
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -78,6 +83,124 @@ def test_spans_by_name_aggregates():
     agg = spans_by_name(rec.records)
     assert agg["train/solve"]["count"] == 3
     assert agg["train/solve"]["min_s"] <= agg["train/solve"]["mean_s"]
+
+
+def _run_child(code: str, *args: str) -> dict:
+    """Run ``code`` in a fresh interpreter (no recorder of this process
+    alive there) and return the JSON object it prints last."""
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [src, os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", code, *args], env=env,
+                         capture_output=True, text=True, timeout=240)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_gc_collection_is_a_py_gc_span():
+    """A collection under a live recorder is a ``py/gc`` span nested in
+    the span it interrupted, with the collector's own numbers."""
+    rec = SpanRecorder()
+    with rec.span("outer"):
+        gc.collect()
+    outer = next(r for r in rec.records if r["name"] == "outer")
+    gcs = [r for r in rec.records if r["name"] == "py/gc"]
+    assert gcs, rec.records
+    full = gcs[-1]
+    assert full["attrs"]["generation"] == 2
+    assert full["attrs"]["collected"] >= 0
+    assert full["depth"] == outer["depth"] + 1
+    assert outer["ts_us"] <= full["ts_us"]
+    assert full["ts_us"] + full["dur_us"] <= outer["ts_us"] \
+        + outer["dur_us"]
+
+
+def test_null_telemetry_installs_no_stall_hooks():
+    """The disabled bundle records no ``py/gc`` and installs no hook; the
+    first live recorder installs each hook once, a second adds none, and
+    a jit compile under it is a ``jax/compile`` span."""
+    got = _run_child(r"""
+import gc, json
+import jax, jax.numpy as jnp
+from jax._src import monitoring
+from repro.obs import NULL_TELEMETRY, SpanRecorder
+from repro.obs import trace as T
+tr = NULL_TELEMETRY.trace
+tok = tr.begin("x")
+gc.collect()
+tr.end(tok)
+off = {"installed": T._gc_hook in gc.callbacks,
+       "listener": T._compile_hook
+       in monitoring._event_duration_secs_listeners,
+       "records": len(tr.records)}
+rec, rec2 = SpanRecorder(), SpanRecorder()
+with rec.span("outer"):
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(5)).block_until_ready()
+    gc.collect()
+names = [r["name"] for r in rec.records]
+comp = [r for r in rec.records if r["name"] == "jax/compile"]
+print(json.dumps({"off": off,
+    "gc_hooks": gc.callbacks.count(T._gc_hook),
+    "listeners": monitoring._event_duration_secs_listeners.count(
+        T._compile_hook),
+    "names": names, "compile_depths": [r["depth"] for r in comp],
+    "compile_events": [r["attrs"]["event"] for r in comp],
+    "second": sorted({r["name"] for r in rec2.records})}))
+""")
+    assert got["off"] == {"installed": False, "listener": False,
+                          "records": 0}
+    assert got["gc_hooks"] == 1 and got["listeners"] == 1
+    assert "py/gc" in got["names"] and "jax/compile" in got["names"]
+    assert set(got["compile_depths"]) <= {1, 2}
+    assert "backend_compile" in got["compile_events"]
+    assert got["second"] == ["jax/compile", "py/gc"]
+
+
+def test_spans_stand_on_the_profilers_host_plane():
+    """Under ``jax.profiler.trace``, every span name the recorder holds
+    (``jax/compile`` aside, recorded after the fact) appears on a host
+    plane of the ``.xplane.pb`` as many times as the recorder has it."""
+    got = _run_child(r"""
+import gc, glob, json, os, sys, tempfile
+import jax, jax.numpy as jnp
+from jax.profiler import ProfileData
+from repro.obs import SpanRecorder
+gc.disable()                 # the one collection is the forced one
+rec = SpanRecorder()
+d = tempfile.mkdtemp()
+with jax.profiler.trace(d):
+    with rec.span("test/outer", k=1):
+        for i in range(3):
+            tok = rec.begin("test/inner", i=i)
+            jax.jit(lambda x: x * 2.0 + i)(jnp.ones(4)).block_until_ready()
+            rec.end(tok, done=True)
+        gc.collect()
+path = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)[0]
+host = {}
+stats = {}
+for plane in ProfileData.from_file(path).planes:
+    if plane.name.startswith("/device:"):
+        continue
+    for line in plane.lines:
+        for ev in line.events:
+            host[ev.name] = host.get(ev.name, 0) + 1
+            if ev.name == "test/inner":
+                stats = {k: str(v) for k, v in dict(ev.stats).items()}
+mine = {}
+for r in rec.records:
+    mine[r["name"]] = mine.get(r["name"], 0) + 1
+print(json.dumps({"host": host, "mine": mine, "stats": stats}))
+""")
+    mine, host = got["mine"], got["host"]
+    assert mine["test/inner"] == 3 and mine["py/gc"] == 1
+    assert mine.get("jax/compile", 0) >= 1
+    for name, n in mine.items():
+        if name != "jax/compile":
+            assert host.get(name) == n, (name, n, host.get(name))
+    assert "jax/compile" not in host
+    # attrs from begin and from end both reach the annotation
+    assert {"i", "done"} <= set(got["stats"])
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,7 @@ from repro.core.math import exp_dirichlet_expectation
 from repro.data import PAPER_CORPORA, make_corpus
 from repro.data.stream import BatchPacker, CorpusDocStream, QueueDocStream
 from repro.lda import LDA
-from repro.obs import ElboWatchdog
+from repro.obs import ElboWatchdog, Telemetry
 from repro.serve import (
     AdmissionController,
     OnlineLearner,
@@ -143,8 +143,9 @@ def test_full_bucket_emits_on_offer():
             batches.append(batch)
     assert len(batches) == 1                 # emitted the moment it filled
     assert len(batches[0].rows) == 4
-    reqs = ac.take(batches[0].rows, now=0.0)
-    assert [r.rid for r in reqs] == [0, 1, 2, 3]
+    taken = ac.take(batches[0].rows, now=0.0)
+    assert [r.rid for r, _ in taken] == [0, 1, 2, 3]
+    assert [t for _, t in taken] == [0.0] * 4     # their admit times
     assert ac.pending == 0
 
 
@@ -155,7 +156,8 @@ def test_timeout_partial_flush():
     assert ac.poll(now=0.049) == []          # not due yet
     out = ac.poll(now=0.05)                  # oldest waited the timeout
     assert len(out) == 1 and len(out[0].rows) == 1
-    assert [r.rid for r in ac.take(out[0].rows, now=0.05)] == [0]
+    assert [(r.rid, t) for r, t in ac.take(out[0].rows, now=0.05)] \
+        == [(0, 0.0)]
     assert ac.poll(now=1.0) == []            # window empty again
 
 
@@ -202,7 +204,7 @@ def test_csr_over_budget_doc_at_head_of_flush_serves_clipped():
     # the clip keeps the most frequent tokens (corpus_from_docs rule)
     assert set(np.asarray(b.token_ids)[np.asarray(b.counts) > 0]) \
         == set(range(24, 40))
-    assert [r.rid for r in ac.take(b.rows, now=0.05)] == [0]
+    assert [r.rid for r, _ in ac.take(b.rows, now=0.05)] == [0]
     assert ac.pending == 0
 
 
@@ -555,3 +557,81 @@ def test_slo_report_attainment_and_validation(tiny_lda):
     bad = dict(rep, offered="3")
     with pytest.raises(ValueError, match="offered"):
         validate_slo_report(bad)
+
+
+def test_service_spans_tile_the_loop(tiny_lda):
+    """With a live bundle the loop's top-level spans tile ``run``: one
+    ``serve/admit`` per offered request (``shed`` on the refused one), one
+    ``serve/respond`` per served batch, and the program's own per-request
+    times in order: admit ≤ batch start ≤ completion."""
+    import time
+
+    inf = tiny_lda.inferencer(batch_size=8)
+    tel = Telemetry()
+    svc = ServingService(inf, config=ServiceConfig(flush_timeout_s=0.005),
+                         telemetry=tel)
+    docs = _ragged(40, seed=20)
+    reqs = requests_from_docs(docs, poisson_arrivals(len(docs), 800.0,
+                                                     seed=1))
+    reqs.append(Request(rid=len(docs), ids=docs[0][0], cnts=docs[0][1],
+                        arrival_s=reqs[-1].arrival_s, deadline_s=0.0))
+    t0 = time.perf_counter_ns()
+    responses = svc.run(reqs)
+    wall_us = (time.perf_counter_ns() - t0) / 1e3
+    spans = [r for r in tel.trace.records if r["type"] == "span"]
+
+    def named(name):
+        return [r for r in spans if r["name"] == name]
+
+    admits = named("serve/admit")
+    assert len(admits) == len(reqs)
+    assert all(r["attrs"]["lag_ms"] >= 0 for r in admits)
+    assert [r["attrs"].get("shed", False) for r in admits] \
+        == [False] * len(docs) + [True]
+    ok = [r for r in responses if r.ok]
+    assert len(ok) == len(docs)
+    batches = {r.start_s for r in ok}
+    assert len(named("serve/respond")) == len(batches) \
+        == len(named("serve/request_batch"))
+    assert sum(r["attrs"]["docs"] for r in named("serve/respond")) \
+        == len(docs)
+    for r in ok:
+        assert r.admit_s <= r.start_s <= r.done_s
+        assert r.admit_s >= r.arrival_s
+    shed = [r for r in responses if r.status == "shed"]
+    assert len(shed) == 1 and shed[0].admit_s is None \
+        and shed[0].start_s is None
+    assert {r["attrs"]["until"] for r in named("serve/wait")} \
+        <= {"arrival", "flush"}
+    assert sum(r["attrs"]["batches"] for r in named("serve/flush")) \
+        + sum(1 for r in named("serve/request_batch") if r["depth"] == 0) \
+        == len(batches)
+    top = sum(r["dur_us"] for r in spans
+              if r["depth"] == 0 and r["name"].startswith("serve/"))
+    assert top >= 0.8 * wall_us, (top, wall_us)
+
+
+def test_service_off_keeps_only_the_latency_histogram(tiny_lda):
+    """Telemetry off: the private registry holds ``serve.latency_ms`` and
+    nothing else; the admission and packer counters go to a live bundle
+    only, and the service leaves ``serve.docs``/``serve.batches`` to the
+    inferencer's width-labelled copies."""
+    docs = _ragged(10, seed=21)
+    reqs = requests_from_docs(docs, replay_arrivals(len(docs)))
+    svc = ServingService(tiny_lda.inferencer(batch_size=8),
+                         config=ServiceConfig(flush_timeout_s=0.005))
+    svc.run(reqs)
+    snap = svc.metrics.snapshot()
+    assert snap["counters"] == [] and snap["gauges"] == []
+    assert [h["name"] for h in snap["histograms"]] == ["serve.latency_ms"]
+    assert svc.admission.metrics is None
+
+    tel = Telemetry()
+    live = ServingService(tiny_lda.inferencer(batch_size=8, telemetry=tel),
+                          config=ServiceConfig(flush_timeout_s=0.005),
+                          telemetry=tel)
+    live.run(reqs)
+    assert tel.metrics.total("serve.docs") == len(docs)
+    assert tel.metrics.total("admit.admitted") == len(docs)
+    assert tel.metrics.total("pack.docs") == len(docs)
+    assert tel.metrics.histogram_values("admit.queue_wait_ms")

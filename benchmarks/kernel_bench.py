@@ -147,15 +147,23 @@ def modeled_estep_hbm_bytes(path: str, b: int, v: int, k: int, l: int,
                  + 2 * (2 * nbd + 1) * v * k * 4 + bk)
         return fixed_point + delta
     # segment-sum pair: token-π kernel reads cnts + the Eφ token cube and
-    # writes π once; the scatter re-streams the π/old_pi rows (plus
-    # ids/cnts) once per V chunk and writes each (V, K) mass exactly once
+    # writes π once; the scatter fetches the π/old_pi rows (plus ids/cnts)
+    # at every row tile it visits and writes each (V, K) mass exactly once
     # from VMEM — no partial spills at all.
-    vc, _ = lda_estep.segment_scatter_blocks(k, v, True)
-    nvc = -(-v // vc)
     delta = (2 * bp * l * 4 + 2 * cube + bk           # token-π kernel
-             + nvc * (2 * cube + 2 * bp * l * 4)      # per-chunk re-streams
+             + _scatter_row_bytes(bp * l, k, v)       # visited row tiles
              + 2 * v * k * 4)                         # S_new/S_old out
     return fixed_point + delta
+
+
+def _scatter_row_bytes(rows: int, k: int, v: int) -> int:
+    """Token-row HBM traffic of the segment scatter (π, old π, id, count
+    per row): one fetch per step of the sorted visit list, plus the sort's
+    read and write of every row."""
+    _, tb = lda_estep.segment_scatter_blocks(k, v, True)
+    _, grid = lda_estep.scatter_grid_steps(rows, k, v, True)
+    row = 2 * k * 4 + 2 * 4
+    return grid * min(tb, rows) * row + 2 * rows * row
 
 
 def modeled_scatter_transient_bytes(path: str, b: int, v: int, k: int,
@@ -165,7 +173,8 @@ def modeled_scatter_transient_bytes(path: str, b: int, v: int, k: int,
     intermediate between the E-step tensors and the (V, K) results, plus
     those results. The one-hot path's per-B-tile (nb, V, K) partial cubes
     dominate it (~2.3 GB at the Arxiv shape); the segment-sum path holds
-    only the row-tile padding remainder — the ≥4× Arxiv bar in
+    only the row-tile padding remainder and its word-sorted copies of the
+    rows — the ≥4× Arxiv bar in
     BENCH_estep.json compares exactly these two numbers.
     """
     bp = -(-b // delta_block_b) * delta_block_b
@@ -181,8 +190,11 @@ def modeled_scatter_transient_bytes(path: str, b: int, v: int, k: int,
         lp = -(-l // bl) * bl
         _, tb = lda_estep.segment_scatter_blocks(k, v, True)
         rows = bp * lp
-        pad_rows = -(-rows // tb) * tb - rows
-        return 2 * (bp * (lp - l) + pad_rows) * k * 4 + results
+        rows_p = -(-rows // tb) * tb
+        pad_rows = rows_p - rows
+        # sorted π/old π copies, plus keys, order and counts
+        sort = rows_p * (2 * k + 3) * 4
+        return 2 * (bp * (lp - l) + pad_rows) * k * 4 + sort + results
     raise ValueError(path)
 
 
@@ -323,8 +335,9 @@ def modeled_estep_csr_hbm_bytes(t: int, b: int, v: int, k: int, iters: int,
       * fixed point: cnts/segs + the cube, once or per-sweep, plus the
         γ0-in/γ-out/Eθ-out block triple;
       * memo pair: the token-π kernel (cnts/segs + cube re-read, Eθ in,
-        π out) and the segment-sum scatter re-streaming the token rows
-        (ids/cnts/π/old_pi) once per V chunk, S_new/S_old written once.
+        π out) and the segment-sum scatter fetching the token rows
+        (ids/cnts/π/old_pi) at each row tile it visits, S_new/S_old
+        written once.
     """
     kp = -(-k // 128) * 128
     bp = -(-b // 8) * 8
@@ -335,10 +348,8 @@ def modeled_estep_csr_hbm_bytes(t: int, b: int, v: int, k: int, iters: int,
     gather = v * k * 4 + tp * 4 + tp * kp * stream_bytes
     tok_fetch = tp * (4 + 4) + tp * kp * stream_bytes
     fixed_point = (1 if resident else iters) * tok_fetch + 3 * bp * kp * 4
-    vc, _ = lda_estep.segment_scatter_blocks(k, v, True)
-    nvc = -(-v // vc)
     delta = (tp * (4 + 4) + tp * k * stream_bytes + bk + tp * k * 4
-             + nvc * (tp * (4 + 4) + 2 * tp * k * 4)  # per-chunk re-streams
+             + _scatter_row_bytes(tp, k, v)           # visited row tiles
              + 2 * v * k * 4)                         # S_new/S_old out
     return gather + fixed_point + delta
 

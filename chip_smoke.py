@@ -12,9 +12,10 @@ documents, with K=100:
   Pallas kernels and CSR layout on the flat-token kernels. λ must stay
   finite, the memoized bound must not decrease once the random-init mass
   has retired (eq. 4), the compiled memo correction must hold Mosaic
-  kernels (``tpu_custom_call``, so nothing ran in interpret mode), and one
-  batch's E-step (γ, π, correction) must match the jnp ``gather``
-  reference;
+  kernels (``tpu_custom_call``, so nothing ran in interpret mode), its
+  scatter kernel must take the sorted visit list (an s32 list of
+  row_tiles + chunks visits) as its first operand, and one batch's E-step
+  (γ, π, correction) must match the jnp ``gather`` reference;
 * serve: a few batches of held-out requests through the serving service in
   both layouts, γ compared with the ``gather`` reference.
 
@@ -95,10 +96,14 @@ def reference(fn, *args, **kwargs):
         return fn(*args, **kwargs)
 
 
-def count_kernels(jitted, *args, **kwargs) -> int:
-    """Mosaic kernel calls in the compiled program (0 = interpret mode)."""
-    text = jitted.lower(*args, **kwargs).compile().as_text()
-    return text.count("custom_call_target=\"tpu_custom_call\"")
+def kernel_calls(jitted, *args, **kwargs):
+    """(kernel name, first operand type) of each Mosaic kernel call in the
+    compiled program (none = interpret mode), named as the benchmark's
+    trace reader names them."""
+    from bench.trace import op_name
+    from repro.launch.hlo_analysis import mosaic_calls
+    compiled = jitted.lower(*args, **kwargs).compile()
+    return [(op_name(line), first) for line, first in mosaic_calls(compiled)]
 
 
 # ---------------------------------------------------------------------------
@@ -207,17 +212,29 @@ def phase_estep(corpus, lda, layout: str) -> None:
 
     if layout == "padded":
         backend = "pallas"
-        n = count_kernels(kops.memo_correction_pallas, cfg, eb, ids, cnts,
-                          old_pi, visited, pi_dtype=wire)
+        calls = kernel_calls(kops.memo_correction_pallas, cfg, eb, ids, cnts,
+                             old_pi, visited, pi_dtype=wire)
+        token_shape = ids.shape
     else:
         backend = "csr"
         tok = get_backend("csr").flatten(BowBatch(ids, cnts))
-        n = count_kernels(kops.memo_correction_pallas_csr, cfg, eb,
-                          tok.token_ids, tok.counts, tok.segments,
-                          old_pi.reshape(-1, old_pi.shape[-1]), visited,
-                          pi_dtype=wire)
-    log(phase, f"compiled memo correction holds {n} Mosaic kernel calls")
-    check(n > 0, f"{phase}: no tpu_custom_call in the compiled correction")
+        calls = kernel_calls(kops.memo_correction_pallas_csr, cfg, eb,
+                             tok.token_ids, tok.counts, tok.segments,
+                             old_pi.reshape(-1, old_pi.shape[-1]), visited,
+                             pi_dtype=wire)
+        token_shape = tok.token_ids.shape
+    log(phase, f"compiled memo correction holds {len(calls)} Mosaic kernel "
+               f"calls")
+    check(len(calls) > 0,
+          f"{phase}: no tpu_custom_call in the compiled correction")
+    # V spans many scatter chunks here: the compiled scatter must walk the
+    # sorted visit list, not the dense chunks × row_tiles grid
+    dense, grid = kops.correction_scatter_steps(cfg, token_shape)
+    scatter = [t for name, t in calls if name == "_segment_scatter_kernel"]
+    log(phase, f"compiled scatter's first operand {scatter} "
+               f"(visit list of {grid} steps; dense grid {dense})")
+    check(scatter == [f"s32[{grid}]"] and grid < dense,
+          f"{phase}: the scatter does not run the sorted visit list")
 
     batch = BowBatch(ids, cnts)
     # at the production tolerance the kernel stops per 128-document tile

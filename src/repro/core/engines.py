@@ -612,12 +612,24 @@ class LDAEngine:
             if self.memo is not None:
                 m.set_gauge("train.memo_resident_bytes",
                             self.memo.footprint_bytes())
+                self._scatter_gauges(m, ids.shape)
             wd = tel.watchdog
             if (self.algo in ("ivi", "sivi") and wd.enabled
                     and wd.should_check(self._updates)):
                 # O(corpus) memoized-bound read — priced by check_every
                 wd.observe(self.full_bound(), step=self._updates,
                            armed=self._watchdog_armed())
+
+    def _scatter_gauges(self, m, token_shape) -> None:
+        """``train.scatter_dense_steps`` / ``train.scatter_grid_steps``: the
+        memo correction's scatter grid for this batch shape, dense
+        ``chunks × row_tiles`` against the sorted visit list run; host
+        arithmetic, set only where the backend runs the scatter kernel."""
+        steps = get_backend(self.cfg.estep_backend).scatter_steps(
+            self.cfg, token_shape)
+        if steps is not None:
+            m.set_gauge("train.scatter_dense_steps", steps[0])
+            m.set_gauge("train.scatter_grid_steps", steps[1])
 
     def _watchdog_armed(self) -> bool:
         """Whether the monotone-ELBO guarantee is in force: IVI (eq. 4 —
@@ -739,6 +751,7 @@ class LDAEngine:
             if self.memo is not None:
                 m.set_gauge("train.memo_resident_bytes",
                             self.memo.footprint_bytes())
+                self._scatter_gauges(m, ids.shape)
             wd = tel.watchdog
             if (self.algo in ("ivi", "sivi") and wd.enabled
                     and wd.should_check(self._updates)):

@@ -42,6 +42,7 @@ contract, (T, K) on the flat one; both are the quantity IVI stores.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Dict, NamedTuple, Optional, Tuple
 
@@ -318,6 +319,13 @@ class EStepBackend:
         words_first = jnp.sum(jnp.where(~visited, cnts.sum(-1), 0.0))
         return correction, words_first, res
 
+    def scatter_steps(self, cfg: LDAConfig, token_shape: Tuple[int, ...]
+                      ) -> Optional[Tuple[int, int]]:
+        """(dense, grid) steps of the memo correction's segment scatter
+        kernel for a batch of ``token_shape`` ((B, L) padded or (T,)
+        flat), or None where the backend runs no such kernel."""
+        return None
+
     # -- flat-token (CSR) contract --------------------------------------
     def solve_tokens(self, cfg: LDAConfig, exp_elog_beta: jax.Array,
                      tok: CSRTokenBatch, num_docs: int,
@@ -383,9 +391,9 @@ class PallasBackend(EStepBackend):
     (`lda_estep.segment_scatter_blocks`): the largest lane-aligned chunk
     whose selector + accumulators fit the kernel's step budget, capped at
     the vocab so small vocabularies run V-resident in a single chunk. The
-    chunk count is the scatter's HBM-traffic knob — the token rows are
-    re-streamed once per chunk — so overriding it only makes sense for
-    benchmark sweeps.
+    rows are sorted by word id and each row tile is fetched only for the
+    chunks its ids meet (``lda_estep.scatter_grid_steps``); overriding the
+    chunk only makes sense for benchmark sweeps.
 
     ``policy`` (a ``repro.core.types.KernelPolicy``) pins every tile knob
     for instances constructed by the autotuner. The module singletons in
@@ -426,6 +434,12 @@ class PallasBackend(EStepBackend):
                                      policy=self.policy,
                                      delta_block_v=self.delta_block_v)
 
+    def scatter_steps(self, cfg, token_shape):
+        from repro.kernels import ops as kops
+        return kops.correction_scatter_steps(
+            cfg, token_shape, policy=self.policy,
+            delta_block_v=self.delta_block_v)
+
     def solve_correction_tokens(self, cfg, exp_elog_beta, tok, old_pi,
                                 visited, pi_dtype="float32"):
         from repro.kernels import ops as kops
@@ -456,6 +470,10 @@ class CSRBackend(PallasBackend):
                                 (b, l))
         return CSRTokenBatch(batch.token_ids.reshape(-1),
                              batch.counts.reshape(-1), segs.reshape(-1))
+
+    def scatter_steps(self, cfg, token_shape):
+        # a padded (B, L) batch runs flattened to B·L tokens
+        return super().scatter_steps(cfg, (math.prod(token_shape),))
 
     def solve(self, cfg, exp_elog_beta, batch, gamma0=None):
         b, l = batch.token_ids.shape

@@ -15,10 +15,11 @@ Production path (`ops.estep_pallas` / `ops.memo_correction_pallas`):
   axes (the L grid axis — VMEM no longer bounds the corpus L) and forms
   π = Eθ⊙Eφ_tok/φnorm per tile; the scatter kernel flattens the batch to
   token rows and accumulates cnt·π_new / cnt·π_old into (V, K) over a
-  second-level **V-chunk grid axis** — the chunk axis is outermost, so
-  each (block_v, K) accumulator is revisited only by grid-consecutive row
-  tiles (the revisit pattern Pallas TPU defines) and hits HBM exactly once
-  per chunk. No dense (nb, V, K) one-hot partials exist anywhere, and the
+  second-level **V-chunk** axis — each (block_v, K) accumulator is
+  revisited only by grid-consecutive steps (the revisit pattern Pallas TPU
+  defines) and hits HBM exactly once per chunk. The rows are sorted by
+  word id and a prefetched visit list walks only the (chunk, row-tile)
+  pairs that meet, so a row tile no longer meets every chunk. No dense (nb, V, K) one-hot partials exist anywhere, and the
   IVI correction still needs **no (B, L, K) jnp intermediates**: the only
   (B, L, K) array XLA sees is the Eφ token gather feeding the kernel.
   The retired one-hot-partial formulation is kept as
@@ -33,7 +34,7 @@ Tiling (DESIGN.md §7 and docs/estep.md): B-tile × V-tile × K — K is padded
 to a multiple of 128 by the wrapper (`ops.py`), V-tiles default to 512 and
 B-tiles to 128, so the fused fixed point's VMEM working set is
 
-    C (128·512) + Eφ (512·128) + γ/Eθ/acc (3·128·128)  ≈ 0.8 MB  « 16 MB
+    C (128·512) + Eφ (512·128) + γ/Eθ/acc (3·128·128)  ≈ 0.8 MB  « 48 MiB
 
 and every matmul hits the MXU with ≥128 on both the lane and the
 contraction dimension. ``stream_dtype=bfloat16`` streams C and Eφ in bf16
@@ -251,52 +252,74 @@ def _token_pi_kernel(quantize: bool, cnts_ref, ebtok_ref, et_ref, pi_ref):
     pi_ref[...] = pi
 
 
-def _segment_scatter_kernel(has_old: bool, *refs):
-    """Segment-sum one tile of token rows into the current V chunk.
+def _segment_scatter_kernel(has_old: bool, chunk_ref, tile_ref, nvis_ref,
+                            *refs):
+    """Segment-sum one tile of token rows into one V chunk.
 
-    Grid ``(V-chunks, row-tiles)`` with the chunk axis OUTER: for a fixed
-    chunk ``j`` the (block_v, K) output block is revisited across the
-    grid-consecutive row tiles, which is exactly the revisit pattern Pallas
-    TPU defines for in-kernel accumulation — so the (V, K) masses build up
-    in VMEM and hit HBM once per chunk, with **no** per-B-tile (nb, V, K)
-    partials. Rows are segmented arithmetically: a row contributes to the
-    chunk its token id falls in (`iota == ids`, count-scaled), everything
-    else multiplies to zero — padded rows carry count 0 and are inert.
+    Rows are segmented arithmetically: a row contributes to the chunk its
+    token id falls in (`iota == ids`, count-scaled), everything else
+    multiplies to zero — padded rows carry count 0 and are inert. The rows
+    arrive sorted by word id, and the 1-D grid walks the prefetched
+    ``(chunk, row-tile)`` visits whose ids meet the chunk
+    (``_scatter_visits``), chunks non-decreasing. The (block_v, K) output
+    block of a chunk is therefore revisited only by grid-consecutive
+    steps, the revisit pattern Pallas TPU defines for in-kernel
+    accumulation, so the (V, K) masses build up in VMEM and hit HBM once
+    per chunk, with **no** per-B-tile (nb, V, K) partials. A chunk's first
+    visit zeroes its block; visits past the live count (``nvis``) repeat
+    the last block and run nothing.
     """
+    i = pl.program_id(0)
+    j = chunk_ref[i]
+    first = (i == 0) | (j != chunk_ref[jnp.maximum(i - 1, 0)])
+    live = i < nvis_ref[0]
     if has_old:
         ids_ref, cnts_ref, wnew_ref, wold_ref, snew_ref, sold_ref = refs
     else:
         ids_ref, cnts_ref, wnew_ref, snew_ref = refs
         wold_ref = sold_ref = None
-    j, t = pl.program_id(0), pl.program_id(1)
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         snew_ref[...] = jnp.zeros_like(snew_ref)
         if has_old:
             sold_ref[...] = jnp.zeros_like(sold_ref)
 
-    bv = snew_ref.shape[0]
-    tb = ids_ref.shape[-1]
-    rows = j * bv + jax.lax.broadcasted_iota(jnp.int32, (bv, tb), 0)
-    # count-scaled segment selector: (bV, T) — doubles as the MXU scatter
-    # operand, so cnt·π never materialises as a separate row array
-    weights = jnp.where(rows == ids_ref[...], cnts_ref[...], 0.0)
-    snew_ref[...] += jax.lax.dot(weights, wnew_ref[...],
-                                 precision=_F32,
-                                 preferred_element_type=jnp.float32)
-    if has_old:
-        sold_ref[...] += jax.lax.dot(weights, wold_ref[...],
+    @pl.when(live)
+    def _accumulate():
+        bv = snew_ref.shape[0]
+        tb = ids_ref.shape[-1]
+        rows = j * bv + jax.lax.broadcasted_iota(jnp.int32, (bv, tb), 0)
+        # count-scaled segment selector: (bV, T) — doubles as the MXU
+        # scatter operand, so cnt·π never materialises as a row array
+        weights = jnp.where(rows == ids_ref[...], cnts_ref[...], 0.0)
+        snew_ref[...] += jax.lax.dot(weights, wnew_ref[...],
                                      precision=_F32,
                                      preferred_element_type=jnp.float32)
+        if has_old:
+            sold_ref[...] += jax.lax.dot(weights, wold_ref[...],
+                                         precision=_F32,
+                                         preferred_element_type=jnp.float32)
 
 
 # VMEM budgets: the token-π step holds two (block_b, block_l, K) fp32 cubes
 # (Eφ tokens in, π out); the scatter step holds the (block_v, T) selector
-# plus one or two (block_v, K) accumulators and (T, K) row tiles. Both kept
-# at half the 16 MB VMEM for the pipeline's double buffering.
+# plus one or two (block_v, K) accumulators and (T, K) row tiles. Each is a
+# sixth of the 48 MiB scoped limit `_PARAMS` sets, which leaves room for the
+# pipeline's double-buffered blocks and the bf16 operand splits of the fp32
+# (HIGHEST) contractions.
 _PI_VMEM_BUDGET = 8 * 1024 * 1024
 _SEG_VMEM_BUDGET = 8 * 1024 * 1024
+
+
+def _pi_l_tile(l: int, block_l: int) -> int:
+    """The token-π kernel's L tile: the whole L, or ``block_l`` past it."""
+    return l if l <= block_l else block_l
+
+
+def _csr_pi_tile(t: int, block_t_pi: int) -> int:
+    """The flat token-π kernel's token tile: lane-aligned, ≤ ``block_t_pi``."""
+    return min(block_t_pi, _round_up(t, 128))
 
 
 def pi_tile_shape(b: int, l: int, k: int, *, block_b: int = 32,
@@ -307,7 +330,7 @@ def pi_tile_shape(b: int, l: int, k: int, *, block_b: int = 32,
     longer bounds VMEM); the B tile is then halved until the two
     (block_b, block_l, K) cubes fit the step budget.
     """
-    bl = l if l <= block_l else block_l
+    bl = _pi_l_tile(l, block_l)
     bb = min(block_b, b)
     while bb > 1 and 2 * bb * bl * k * 4 > _PI_VMEM_BUDGET:
         nxt = bb // 2
@@ -323,8 +346,8 @@ def segment_scatter_blocks(k: int, vocab_size: int, has_old: bool, *,
     ``block_v`` is the second-level V-chunk: the largest multiple of 128
     whose selector + accumulators fit ``_SEG_VMEM_BUDGET`` (capped at the
     lane-aligned vocab, so small vocabs run V-resident in one chunk). The
-    scatter re-streams the token rows once per chunk, so bigger chunks mean
-    fewer re-streams — the chunk count is the path's traffic knob.
+    rows are sorted by word id, so each row tile meets only the chunks its
+    ids fall in (``scatter_grid_steps``).
     """
     nacc = 2 if has_old else 1
 
@@ -341,6 +364,118 @@ def segment_scatter_blocks(k: int, vocab_size: int, has_old: bool, *,
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def scatter_rows(token_shape: Tuple[int, ...], *, block_l: int = 512) -> int:
+    """Token rows the segment scatter flattens for a ``memo_delta`` call on
+    (B, L) token ids (L padded to the π kernel's L tile) or a
+    ``memo_delta_csr`` call on (T,) flat tokens (T padded to its token
+    tile); ``block_l`` is ``block_l`` or ``block_t_pi`` respectively."""
+    if len(token_shape) == 2:
+        b, l = token_shape
+        return b * _round_up(l, _pi_l_tile(l, block_l))
+    (t,) = token_shape
+    return _round_up(t, _csr_pi_tile(t, block_l))
+
+
+def scatter_grid_steps(rows: int, k: int, vocab_size: int, has_old: bool, *,
+                       block_v: int | None = None,
+                       block_t: int = 128) -> Tuple[int, int]:
+    """(dense steps, grid steps) of the segment scatter over ``rows`` rows.
+
+    The dense count is ``chunks × row_tiles``: every row tile against every
+    V chunk, the grid the unsorted rows would need. The grid run is the
+    sorted visit list's static length ``row_tiles + chunks`` — a chunk's
+    rows are contiguous once sorted, so only the tiles at chunk boundaries
+    are visited twice, and an empty chunk once. ``_segment_scatter`` takes
+    its grid from here. Pure host arithmetic.
+    """
+    vc, tb = segment_scatter_blocks(k, vocab_size, has_old,
+                                    block_v=block_v, block_t=block_t)
+    nt = -(-rows // min(tb, rows))
+    nv = -(-vocab_size // vc)
+    return nv * nt, nt + nv
+
+
+def _scatter_visits(keys: jax.Array, nv: int, vc: int, tb: int, steps: int):
+    """The (chunk, row-tile) visit list over word-sorted row keys.
+
+    ``keys`` (R,) are the sorted word ids, with the sentinel ``nv·vc`` on
+    inert rows so they sort last and meet no chunk. Chunk c's rows are the
+    contiguous run ``[start_c, end_c)``; it is visited at the row tiles
+    that run spans, or once (at the tile where it would start) when it is
+    empty, so that its output block is zeroed and written. Chunks and
+    tiles are both non-decreasing along the list. Returns (chunk (G,),
+    tile (G,), live count (1,)) int32 with ``G = steps``, the grid length
+    ``scatter_grid_steps`` gives (tiles + chunks); the entries past the
+    live count repeat the last visit.
+    """
+    nt = keys.shape[0] // tb
+    edges = jnp.arange(nv + 1, dtype=jnp.int32) * vc
+    bounds = jnp.searchsorted(keys, edges, side="left").astype(jnp.int32)
+    start, end = bounds[:-1], bounds[1:]
+    first = jnp.minimum(start // tb, nt - 1)
+    last = jnp.where(end > start, (end - 1) // tb, first)
+    count = last - first + 1                          # ≥ 1 visit a chunk
+    stop = jnp.cumsum(count)                          # inclusive ends
+    step = jnp.arange(steps, dtype=jnp.int32)
+    chunk = jnp.minimum(jnp.searchsorted(stop, step, side="right"),
+                        nv - 1).astype(jnp.int32)
+    tile = first[chunk] + step - (stop - count)[chunk]
+    live = step < stop[-1]
+    tile = jnp.where(live, tile, last[nv - 1])
+    return chunk, tile.astype(jnp.int32), stop[-1:].astype(jnp.int32)
+
+
+def _segment_scatter(ids: jax.Array, cnts: jax.Array, rows_w,
+                     vocab_size: int, block_v: int | None, block_t: int,
+                     interpret: bool):
+    """Σ cnt·w over flat token rows into (V, K) masses, one per ``rows_w``.
+
+    ids/cnts (R,), each of ``rows_w`` (R, K) (π_new, then π_old if any).
+    The rows are sorted by word id (inert rows last) and the kernel walks
+    the visit list of ``_scatter_visits``: a grid of ``row_tiles +
+    chunks`` steps in place of ``chunks × row_tiles``.
+    """
+    has_old = len(rows_w) == 2
+    k = rows_w[0].shape[1]
+    r = ids.shape[0]
+    vc, tb = segment_scatter_blocks(k, vocab_size, has_old,
+                                    block_v=block_v, block_t=block_t)
+    _, steps = scatter_grid_steps(r, k, vocab_size, has_old,
+                                  block_v=block_v, block_t=block_t)
+    tb = min(tb, r)
+    rows_p = _round_up(r, tb)
+    if rows_p != r:
+        ids, cnts = (jnp.pad(x, (0, rows_p - r)) for x in (ids, cnts))
+        rows_w = [jnp.pad(w, ((0, rows_p - r), (0, 0))) for w in rows_w]
+    nt = rows_p // tb
+    vp = _round_up(vocab_size, vc)
+    # inert rows take the sentinel vp: they sort last and meet no chunk
+    keys = jnp.where(cnts > 0, ids, vp).astype(jnp.int32)
+    keys, order = jax.lax.sort(
+        (keys, jnp.arange(rows_p, dtype=jnp.int32)), num_keys=1,
+        is_stable=True)
+    cnts = cnts[order]
+    rows_w = [w[order] for w in rows_w]
+    chunk, tile, nvis = _scatter_visits(keys, vp // vc, vc, tb, steps)
+    # ids/counts ride as (nt, 1, tb): the squeezed row-tile axis makes each
+    # (1, tb) block span the last two dims, as Mosaic's tiling rule needs
+    row_spec = pl.BlockSpec((None, 1, tb), lambda i, c, t, n: (t[i], 0, 0))
+    w_spec = pl.BlockSpec((tb, k), lambda i, c, t, n: (t[i], 0))
+    acc_spec = pl.BlockSpec((vc, k), lambda i, c, t, n: (c[i], 0))
+    n_out = len(rows_w)
+    outs = pl.pallas_call(
+        functools.partial(_segment_scatter_kernel, has_old),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(steps,),
+            in_specs=[row_spec, row_spec] + [w_spec] * n_out,
+            out_specs=[acc_spec] * n_out),
+        out_shape=[jax.ShapeDtypeStruct((vp, k), jnp.float32)] * n_out,
+        compiler_params=_PARAMS, interpret=interpret,
+    )(chunk, tile, nvis, keys.reshape(nt, 1, tb), cnts.reshape(nt, 1, tb),
+      *rows_w)
+    return [o[:vocab_size] for o in outs]
 
 
 def memo_delta(token_ids: jax.Array, counts: jax.Array, eb_tok: jax.Array,
@@ -360,13 +495,18 @@ def memo_delta(token_ids: jax.Array, counts: jax.Array, eb_tok: jax.Array,
     Two ``pallas_call``s because the two outputs want opposite grid
     orders: π blocks pin the (B, L) axes as owners (each written once),
     while the (V, K) masses accumulate over ALL rows — which is only
-    TPU-safe with the V-chunk axis outermost (grid-consecutive revisits).
+    TPU-safe when each (block_v, K) block's revisits are grid-consecutive.
     The first kernel tiles (B, L) — the **L grid axis** that removes the
     old L ≤ ~4k VMEM cap — and emits π (quantized through the memo wire
     dtype when asked). The second flattens the rows and segment-sums them
     into (V, K) chunk by chunk: no dense (nb, V, K) one-hot partials
-    exist anywhere, the only transient beyond the outputs is the
-    row-padding remainder. The retired partial formulation is kept as
+    exist anywhere. The rows (ids, counts, π_new, π_old) are first sorted
+    by word id, zero-count rows last, and the scatter visits only the
+    (chunk, row-tile) pairs whose ids meet — ``row_tiles + chunks`` grid
+    steps in place of ``chunks × row_tiles`` (``scatter_grid_steps``). The
+    returned π stays in document order; the masses differ from a scatter
+    in document order only by the fp32 summation order within a word's
+    rows. The retired partial formulation is kept as
     ``memo_delta_onehot`` (benchmark baseline).
 
     B must divide by the effective B-tile (pad upstream with zero-count
@@ -404,50 +544,16 @@ def memo_delta(token_ids: jax.Array, counts: jax.Array, eb_tok: jax.Array,
         interpret=interpret,
     )(cnts_p, ebt_p, etheta)
 
-    # -- kernel 2: segment-sum scatter over the V-chunk grid ------------
-    vc, tb = segment_scatter_blocks(k, vocab_size, has_old,
-                                    block_v=block_v, block_t=block_t)
+    # -- kernel 2: segment-sum scatter over the V chunks -----------------
     rows = b * lp
-    tb = min(tb, rows)
-    rows_p = _round_up(rows, tb)
-    nt = rows_p // tb
-
-    def _flat_rows(x, width):
-        flat = x.reshape(rows, *((width,) if width else ()))
-        if rows_p == rows:
-            return flat
-        pad = ((0, rows_p - rows),) + ((0, 0),) * (flat.ndim - 1)
-        return jnp.pad(flat, pad)
-
-    ids2 = _flat_rows(ids_p, None).reshape(nt, 1, tb)
-    cnts2 = _flat_rows(cnts_p, None).reshape(nt, 1, tb)
-    wnew = _flat_rows(pi_pad, k)
-    inputs = [ids2, cnts2, wnew]
+    rows_w = [pi_pad.reshape(rows, k)]
     if has_old:
-        inputs.append(_flat_rows(_pad_l(old_pi), k))
-
-    vp = _round_up(vocab_size, vc)
-    # ids/counts ride as (nt, 1, tb): the squeezed row-tile axis makes each
-    # (1, tb) block span the last two dims, as Mosaic's tiling rule needs
-    row_spec = pl.BlockSpec((None, 1, tb), lambda j, t: (t, 0, 0))
-    w_spec = pl.BlockSpec((tb, k), lambda j, t: (t, 0))
-    acc_spec = pl.BlockSpec((vc, k), lambda j, t: (j, 0))
-    n_out = 2 if has_old else 1
-    outs = pl.pallas_call(
-        functools.partial(_segment_scatter_kernel, has_old),
-        grid=(vp // vc, nt),
-        in_specs=[row_spec, row_spec, w_spec] + [w_spec] * (n_out - 1),
-        out_specs=[acc_spec] * n_out,
-        out_shape=[jax.ShapeDtypeStruct((vp, k), jnp.float32)] * n_out,
-        compiler_params=_PARAMS,
-        interpret=interpret,
-    )(*inputs)
-
+        rows_w.append(_pad_l(old_pi).reshape(rows, k))
+    masses = _segment_scatter(ids_p.reshape(rows), cnts_p.reshape(rows),
+                              rows_w, vocab_size, block_v, block_t,
+                              interpret)
     pi = pi_pad if lp == l else pi_pad[:, :l]
-    snew = outs[0][:vocab_size]
-    if has_old:
-        return pi, snew, outs[1][:vocab_size]
-    return pi, snew
+    return (pi, *masses)
 
 
 # ---------------------------------------------------------------------------
@@ -631,9 +737,9 @@ def memo_delta_csr(token_ids: jax.Array, counts: jax.Array,
     The CSR twin of ``memo_delta``: token_ids/counts/segs are the flat
     (T,) stream, eb_tok (T, K) the Eφ token gather, old_pi the memoized π
     in the SAME flat layout. Returns (π (T, K), S_new (V, K)[, S_old]).
-    The scatter is the unchanged ``_segment_scatter_kernel`` — it always
-    operated on flattened token rows, so the CSR layout is its native
-    input and the (B, L) reshape simply disappears.
+    The scatter is the same ``_segment_scatter_kernel``, sorted visit list
+    included — it always operated on flattened token rows, so the CSR
+    layout is its native input and the (B, L) reshape simply disappears.
     """
     t = token_ids.shape[0]
     k = etheta.shape[1]
@@ -641,7 +747,7 @@ def memo_delta_csr(token_ids: jax.Array, counts: jax.Array,
     interpret = _default_interpret(interpret)
 
     # -- kernel 1: token-aligned π over the flat token grid -------------
-    bt = min(block_t_pi, _round_up(t, 128))
+    bt = _csr_pi_tile(t, block_t_pi)
     tp = _round_up(t, bt)
 
     def _pad_t(x):
@@ -669,44 +775,11 @@ def memo_delta_csr(token_ids: jax.Array, counts: jax.Array,
     )(cnts_p.reshape(nj, 1, bt), segs_p.reshape(nj, 1, bt), ebt_p, etheta)
 
     # -- kernel 2: the SAME segment-sum scatter as the padded path ------
-    vc, tb = segment_scatter_blocks(k, vocab_size, has_old,
-                                    block_v=block_v, block_t=block_t)
-    tb = min(tb, tp)
-    rows_p = _round_up(tp, tb)
-
-    def _scatter_rows(x):
-        if rows_p == tp:
-            return x
-        pad = ((0, rows_p - tp),) + ((0, 0),) * (x.ndim - 1)
-        return jnp.pad(x, pad)
-
-    nt = rows_p // tb
-    ids2 = _scatter_rows(ids_p).reshape(nt, 1, tb)
-    cnts2 = _scatter_rows(cnts_p).reshape(nt, 1, tb)
-    inputs = [ids2, cnts2, _scatter_rows(pi_pad)]
-    if has_old:
-        inputs.append(_scatter_rows(_pad_t(old_pi)))
-
-    vp = _round_up(vocab_size, vc)
-    row_spec = pl.BlockSpec((None, 1, tb), lambda j, t: (t, 0, 0))
-    w_spec = pl.BlockSpec((tb, k), lambda j, t: (t, 0))
-    acc_spec = pl.BlockSpec((vc, k), lambda j, t: (j, 0))
-    n_out = 2 if has_old else 1
-    outs = pl.pallas_call(
-        functools.partial(_segment_scatter_kernel, has_old),
-        grid=(vp // vc, nt),
-        in_specs=[row_spec, row_spec, w_spec] + [w_spec] * (n_out - 1),
-        out_specs=[acc_spec] * n_out,
-        out_shape=[jax.ShapeDtypeStruct((vp, k), jnp.float32)] * n_out,
-        compiler_params=_PARAMS,
-        interpret=interpret,
-    )(*inputs)
-
+    rows_w = [pi_pad] + ([_pad_t(old_pi)] if has_old else [])
+    masses = _segment_scatter(ids_p, cnts_p, rows_w, vocab_size, block_v,
+                              block_t, interpret)
     pi = pi_pad if tp == t else pi_pad[:t]
-    snew = outs[0][:vocab_size]
-    if has_old:
-        return pi, snew, outs[1][:vocab_size]
-    return pi, snew
+    return (pi, *masses)
 
 
 # ---------------------------------------------------------------------------
@@ -758,8 +831,8 @@ def _memo_delta_onehot_kernel(block_v: int, has_old: bool, quantize: bool,
 
 
 # VMEM budget for one one-hot memo_delta grid step (≈4 (block_b, L, K) fp32
-# cubes plus the (block_v, block_b·L) one-hot), kept at half of the 16 MB
-# VMEM to leave room for the pipeline's double buffering. The wrapper
+# cubes plus the (block_v, block_b·L) one-hot), kept at a sixth of the
+# 48 MiB scoped limit to leave room for double buffering. The wrapper
 # halves block_b until the step fits; the L axis is NOT tiled here, which
 # is the L ≤ ~4k cap the segment-sum path removes.
 _DELTA_VMEM_BUDGET = 8 * 1024 * 1024

@@ -132,6 +132,32 @@ def effective_fixed_point_blocks(b: int, v: int, k: int, *,
     return block_b, block_v, False
 
 
+def correction_scatter_steps(cfg: LDAConfig, token_shape: Tuple[int, ...], *,
+                             policy: Optional[KernelPolicy] = None,
+                             delta_block_v: Optional[int] = None
+                             ) -> Tuple[int, int]:
+    """(dense, grid) steps of the segment scatter in the memo correction.
+
+    ``token_shape`` is the batch's (B, L) for ``memo_correction_pallas`` or
+    (T,) for ``memo_correction_pallas_csr``; the rows, tiles and V chunks
+    are those the call runs (``lda_estep.scatter_grid_steps``): dense
+    ``chunks × row_tiles`` against the sorted visit list's ``row_tiles +
+    chunks``. Host arithmetic on static shapes: nothing is read from the
+    device.
+    """
+    pol = resolve_policy(cfg, policy)
+    if len(token_shape) == 2:
+        b, l = token_shape
+        rows = lda_estep.scatter_rows((_round_up(b, pol.delta_block_b), l),
+                                      block_l=pol.pi_block_l)
+    else:
+        rows = lda_estep.scatter_rows(token_shape, block_l=pol.pi_block_l)
+    block_v = pol.delta_block_v if delta_block_v is None else delta_block_v
+    return lda_estep.scatter_grid_steps(
+        rows, cfg.num_topics, cfg.vocab_size, True, block_v=block_v,
+        block_t=pol.scatter_block_t)
+
+
 def _run_fixed_point(cfg: LDAConfig, exp_elog_beta: jax.Array,
                      token_ids: jax.Array, counts: jax.Array,
                      gamma0: Optional[jax.Array], block_b: int, block_v: int):

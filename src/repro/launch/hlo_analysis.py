@@ -217,6 +217,23 @@ _ARITH_PRIMS = frozenset({
 })
 
 
+def mosaic_calls(compiled) -> List[Tuple[str, str]]:
+    """(HLO line, first operand type) of each Mosaic kernel call in a
+    compiled TPU program, e.g. ``("%custom-call.3 = ...", "s32[353]")``.
+
+    The lines are printed with typed operands, the form the profiler's op
+    events carry, so ``bench.trace.op_name`` names each line's kernel.
+    """
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_metadata = False
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return [(line.strip(), line.split("custom-call(", 1)[1].split("{", 1)[0])
+            for line in text.splitlines()
+            if "tpu_custom_call" in line and "custom-call(" in line]
+
+
 def pallas_call_sites(fn, *args, **kwargs) -> Dict[str, int]:
     """Count Pallas kernel-launch sites in ``fn``'s jaxpr.
 

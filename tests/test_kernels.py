@@ -122,35 +122,93 @@ def test_memo_delta_onehot_multi_tile_partials(b, l, block_b, rng):
     np.testing.assert_allclose(sold, soldref, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("b,l,v,kwargs", [
+def _scatter_case_ids(kind, b, l, v, rng):
+    """Token ids and counts laid out to hit one edge of the sorted scatter
+    (chunks of 128 words in the cases that set block_v=128)."""
+    cnts = rng.poisson(1.0, (b, l)).astype(np.float32)
+    if kind == "random":
+        ids = rng.integers(0, v, (b, l))
+    elif kind == "empty-chunks":     # chunks 0, 5, 11 of 16; the rest empty
+        ids = rng.choice([3, 5 * 128 + 7, 5 * 128 + 100, 11 * 128 + 64],
+                         (b, l))
+    elif kind == "straddle":         # sorted tiles cross the 128 boundary
+        ids = rng.integers(100, 160, (b, l))
+    elif kind == "all-zero":
+        ids = rng.integers(0, v, (b, l))
+        cnts = np.zeros((b, l), np.float32)
+    elif kind == "one-word":         # one chunk takes every tile
+        ids = np.full((b, l), 300)
+        cnts = cnts + 1.0
+    elif kind == "last-chunk":       # the last chunk, 640..767, is partial
+        ids = rng.integers(640, v, (b, l))
+    else:
+        raise ValueError(kind)
+    return jnp.asarray(ids.astype(np.int32)), jnp.asarray(cnts)
+
+
+@pytest.mark.parametrize("b,l,v,kwargs,kind", [
     # L grid axis: 2 L-tiles × 2 B-tiles (the old path capped L at ~4k —
     # this exercises the tiling machinery, test_estep_backend covers 8192)
-    (8, 700, 300, dict(block_l=512, block_b=4)),
+    pytest.param(8, 700, 300, dict(block_l=512, block_b=4), "random",
+                 id="8-700-300-kwargs0"),
     # V-chunk grid axis: 6 chunks over a non-lane-multiple vocab, and a
     # row count that pads up to the T tile
-    (12, 37, 700, dict(block_v=128, block_t=64)),
-    # single-chunk V-resident degenerate case
-    (16, 24, 200, dict()),
+    pytest.param(12, 37, 700, dict(block_v=128, block_t=64), "random",
+                 id="12-37-700-kwargs1"),
+    # single-chunk V-resident degenerate case: a visit list of one chunk
+    pytest.param(16, 24, 200, dict(), "random", id="16-24-200-kwargs2"),
+    # the sorted visit list: empty chunks between visited ones
+    pytest.param(8, 40, 2000, dict(block_v=128, block_t=64), "empty-chunks",
+                 id="empty-chunks"),
+    # a sorted row tile holds the end of one chunk and the start of the next
+    pytest.param(8, 40, 512, dict(block_v=128, block_t=64), "straddle",
+                 id="straddle"),
+    # every row inert: no live visit, every chunk zeroed once
+    pytest.param(4, 50, 700, dict(block_v=128, block_t=64), "all-zero",
+                 id="all-zero"),
+    # all ids equal: one chunk takes every tile, the others are empty
+    pytest.param(6, 30, 700, dict(block_v=128, block_t=64), "one-word",
+                 id="one-word"),
+    # ids only in the last, partial chunk
+    pytest.param(8, 33, 700, dict(block_v=128, block_t=64), "last-chunk",
+                 id="last-chunk"),
+    # V-resident with a padded last row tile: one chunk, trailing inert tile
+    pytest.param(4, 50, 1000, dict(), "random", id="resident"),
 ])
-def test_memo_delta_segment_grid(b, l, v, kwargs, rng):
+def test_memo_delta_segment_grid(b, l, v, kwargs, kind, rng):
     """The segment-sum scatter must match the jnp scatter across the
-    (B, L) tiling of the token-π kernel and the V-chunk grid of the
-    accumulator — including padded L remainders and padded row tiles,
-    which must stay inert (count 0)."""
+    (B, L) tiling of the token-π kernel and the V chunks of the
+    accumulator — the sorted visit list over one chunk and over many —
+    including padded L remainders and padded row tiles, which must stay
+    inert (count 0), with and without old π, on the fp32 and the bf16
+    wire."""
     k = 128
-    ids = jnp.asarray(rng.integers(0, v, (b, l)).astype(np.int32))
-    cnts = jnp.asarray(rng.poisson(1.0, (b, l)).astype(np.float32))
+    ids, cnts = _scatter_case_ids(kind, b, l, v, rng)
     ebt = jnp.asarray(rng.gamma(1.0, 1.0, (b, l, k)).astype(np.float32))
     et = jnp.asarray(rng.gamma(1.0, 1.0, (b, k)).astype(np.float32))
     opi = jnp.asarray(rng.random((b, l, k)).astype(np.float32))
-    pi, snew, sold = lda_estep.memo_delta(ids, cnts, ebt, et, v,
-                                          old_pi=opi, **kwargs)
     pref, sref = _memo_delta_refs(ids, cnts, ebt, et, v)
     soldref = jnp.zeros((v, k)).at[ids.reshape(-1)].add(
         (cnts[:, :, None] * opi).reshape(-1, k))
-    np.testing.assert_allclose(pi, pref, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(snew, sref, rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(sold, soldref, rtol=1e-4, atol=1e-4)
+    for quantize in (False, True):
+        pi, snew, sold = lda_estep.memo_delta(ids, cnts, ebt, et, v,
+                                              old_pi=opi, quantize=quantize,
+                                              **kwargs)
+        pi1, snew1 = lda_estep.memo_delta(ids, cnts, ebt, et, v,
+                                          quantize=quantize, **kwargs)
+        if quantize:
+            # π rounds through bf16 before the scatter: the masses must
+            # hold exactly the rounded π the kernel returns
+            np.testing.assert_allclose(pi, pref, rtol=2 ** -8, atol=1e-6)
+            sexp = jnp.zeros((v, k)).at[ids.reshape(-1)].add(
+                (cnts[:, :, None] * pi).reshape(-1, k))
+        else:
+            np.testing.assert_allclose(pi, pref, rtol=1e-5, atol=1e-6)
+            sexp = sref
+        np.testing.assert_array_equal(np.asarray(pi1), np.asarray(pi))
+        np.testing.assert_allclose(snew, sexp, rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(snew1, sexp, rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(sold, soldref, rtol=1e-4, atol=1e-4)
 
 
 def test_memo_delta_matches_onehot_baseline(rng):
@@ -180,6 +238,70 @@ def test_segment_scatter_blocks_policy():
     bb, bl = lda_estep.pi_tile_shape(32, 8192, 128)
     assert bl == 512 and 2 * bb * bl * 128 * 4 <= 8 * 1024 * 1024
     assert 32 % bb == 0
+
+
+ARXIV_B, ARXIV_L, ARXIV_V, ARXIV_K = 256, 159, 141_927, 100
+
+
+def test_memo_delta_scatter_grid_is_visit_list():
+    """At Arxiv shapes (35 V-chunks × 318 row tiles) the scatter call runs
+    the sorted visit list — at most row_tiles + chunks grid steps, not
+    chunks × row_tiles — and keeps an s32 array as its first operand."""
+    f32 = jnp.float32
+    b, l, v, k = ARXIV_B, ARXIV_L, ARXIV_V, ARXIV_K
+    sds = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(
+        lambda ids, c, ebt, et, old: lda_estep.memo_delta(
+            ids, c, ebt, et, v, old, quantize=True))(
+        sds((b, l), jnp.int32), sds((b, l), f32), sds((b, l, k), f32),
+        sds((b, k), f32), sds((b, l, k), f32))
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    scatter = [e for e in calls if e.invars[0].aval.dtype == jnp.int32]
+    assert len(calls) == 2 and len(scatter) == 1
+    grid = scatter[0].params["grid_mapping"].grid
+    vc, tb = lda_estep.segment_scatter_blocks(k, v, True)
+    chunks, tiles = -(-v // vc), b * l // tb
+    assert (chunks, tiles) == (35, 318)
+    assert len(grid) == 1 and grid[0] <= tiles + chunks
+    assert grid[0] == lda_estep.scatter_grid_steps(b * l, k, v, True)[1]
+
+
+def test_scatter_grid_steps():
+    """The host count behind the train.scatter_* gauges and the kernel's
+    grid: dense is chunks × row_tiles, the grid the visit list's
+    row_tiles + chunks, one chunk included."""
+    f = lda_estep.scatter_grid_steps
+    rows = lda_estep.scatter_rows((ARXIV_B, ARXIV_L))
+    assert rows == 40_704
+    assert f(rows, ARXIV_K, ARXIV_V, True) == (35 * 318, 318 + 35)
+    assert f(rows, ARXIV_K, 700, True) == (318, 318 + 1)   # one chunk
+    assert f(rows, ARXIV_K, 700, True, block_v=128) == (6 * 318, 318 + 6)
+    assert f(100, 64, 500, False) == (1, 1 + 1)            # rows < tile
+    # the π kernel's padding: L past its tile rounds up; T to its tile
+    assert lda_estep.scatter_rows((4, 700), block_l=512) == 4 * 1024
+    assert lda_estep.scatter_rows((8192,), block_l=512) == 8192
+    assert lda_estep.scatter_rows((300,), block_l=512) == 384
+
+
+def test_memo_delta_sorted_under_vmap(rng):
+    """D-IVI vmaps the memo correction over workers: the sorted scatter's
+    prefetched visit lists differ per worker and must batch exactly."""
+    w, b, l, v, k = 3, 8, 40, 700, 128
+    ids = jnp.asarray(rng.integers(0, v, (w, b, l)).astype(np.int32))
+    cnts = jnp.asarray(rng.poisson(1.0, (w, b, l)).astype(np.float32))
+    ebt = jnp.asarray(rng.gamma(1.0, 1.0, (w, b, l, k)).astype(np.float32))
+    et = jnp.asarray(rng.gamma(1.0, 1.0, (w, b, k)).astype(np.float32))
+    opi = jnp.asarray(rng.random((w, b, l, k)).astype(np.float32))
+
+    def f(*a):
+        return lda_estep.memo_delta(*a[:4], v, a[4], block_v=128,
+                                    block_t=64)
+    out = jax.vmap(f)(ids, cnts, ebt, et, opi)
+    for x in range(w):
+        for got, want in zip(out, f(ids[x], cnts[x], ebt[x], et[x],
+                                    opi[x])):
+            np.testing.assert_array_equal(np.asarray(got[x]),
+                                          np.asarray(want))
 
 
 def test_delta_effective_block_b_guard():

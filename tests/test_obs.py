@@ -357,6 +357,40 @@ def test_enabled_telemetry_matches_and_records(tiny_corpus):
     assert tel.metrics.value("train.effective_topics") > 0
 
 
+@pytest.mark.parametrize("backend,layout,block_v,steps", [
+    ("pallas", "padded", None, (5, 6)),   # V = 250 in one chunk: 5 + 1
+    ("pallas", "padded", 128, (10, 7)),   # 2 chunks × 5 row tiles → 5 + 2
+    ("csr", "padded", 128, (6, 5)),       # 16 × 17 flat tokens, 3 tiles
+    ("csr", "csr", 128, (16, 10)),        # token budget 1,024: 8 tiles
+    ("gather", "padded", None, None),     # no scatter kernel: no gauges
+])
+def test_scatter_step_gauges(tiny_corpus, backend, layout, block_v, steps):
+    """An IVI step records the memo correction's scatter grid, dense
+    against run, from the batch's static shape."""
+    from repro.core.types import KernelPolicy
+    from repro.data.stream import as_doc_stream
+    corpus, spec = tiny_corpus
+    cfg = LDAConfig(num_topics=4, vocab_size=spec.vocab_size,
+                    estep_max_iters=5, estep_backend=backend,
+                    kernel_policy=(KernelPolicy(delta_block_v=block_v)
+                                   if block_v else None))
+    tel = Telemetry()
+    if layout == "csr":
+        eng = LDAEngine(cfg, as_doc_stream(corpus), algo="ivi",
+                        batch_size=16, seed=0, telemetry=tel, layout="csr",
+                        memo_store="chunked")
+        assert eng.stream_step()
+    else:
+        eng = LDAEngine(cfg, corpus, algo="ivi", batch_size=16, seed=0,
+                        telemetry=tel)
+        eng.run_minibatch(np.arange(16))
+    gauges = {g["name"]: g["value"]
+              for g in tel.metrics.snapshot()["gauges"]}
+    got = (gauges.get("train.scatter_dense_steps"),
+           gauges.get("train.scatter_grid_steps"))
+    assert got == (steps or (None, None))
+
+
 def test_watchdog_catches_real_bound_decrease(tiny_corpus):
     """Corrupting the memo mid-run breaks eq. 4's subtract-old bookkeeping —
     exactly the failure class the watchdog exists for — and the next armed

@@ -15,6 +15,8 @@ test file. Keep every such test in this one file.
 from __future__ import annotations
 
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import lda_estep
+from repro.launch.hlo_analysis import mosaic_calls
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+from bench.trace import op_name  # noqa: E402
 
 V = 141_927                      # Arxiv vocabulary
 V_PAD = 142_336                  # padded to the fixed point's 512-wide V-tile
@@ -67,6 +75,23 @@ def no_persistent_cache():
 def _assert_mosaic(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _kernel_calls(compiled):
+    """(kernel name, first operand type) of each Pallas call, named by
+    ``bench.trace.op_name``."""
+    return [(op_name(line), first) for line, first in mosaic_calls(compiled)]
+
+
+def _assert_sorted_scatter(compiled, rows):
+    """The scatter is named ``_segment_scatter_kernel`` and runs the sorted
+    visit list: its first operand is the s32 chunk list of
+    row_tiles + chunks visits, fewer than chunks × row_tiles."""
+    dense, grid = lda_estep.scatter_grid_steps(rows, K, V, True)
+    assert grid < dense
+    calls = _kernel_calls(compiled)
+    assert ("_segment_scatter_kernel", f"s32[{grid}]") in calls, calls
 
 
 @pytest.mark.parametrize("stream", [jnp.float32, jnp.bfloat16],
@@ -97,8 +122,10 @@ def test_memo_delta_compiles(spec, quantize):
     def fn(ids, cnts, ebt, et, old):
         return lda_estep.memo_delta(ids, cnts, ebt, et, V, old,
                                     quantize=quantize, interpret=False)
-    _assert_mosaic(fn, spec((B, L), jnp.int32), spec((B, L)),
-                   spec((B, L, K)), spec((B, K)), spec((B, L, K)))
+    compiled = _assert_mosaic(fn, spec((B, L), jnp.int32), spec((B, L)),
+                              spec((B, L, K)), spec((B, K)),
+                              spec((B, L, K)))
+    _assert_sorted_scatter(compiled, B * L)
 
 
 @pytest.mark.parametrize("quantize", [False, True], ids=["f32", "bf16-wire"])
@@ -106,6 +133,7 @@ def test_memo_delta_csr_compiles(spec, quantize):
     def fn(ids, cnts, segs, ebt, et, old):
         return lda_estep.memo_delta_csr(ids, cnts, segs, ebt, et, V, old,
                                         quantize=quantize, interpret=False)
-    _assert_mosaic(fn, spec((T,), jnp.int32), spec((T,)),
-                   spec((T,), jnp.int32), spec((T, K)), spec((B, K)),
-                   spec((T, K)))
+    compiled = _assert_mosaic(fn, spec((T,), jnp.int32), spec((T,)),
+                              spec((T,), jnp.int32), spec((T, K)),
+                              spec((B, K)), spec((T, K)))
+    _assert_sorted_scatter(compiled, T)

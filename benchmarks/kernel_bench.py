@@ -254,7 +254,7 @@ def estep_report(json_path: str | None = None):
             ("fused_bf16", fused_bf16_correction, cfg_bf16, 2)):
         us = time_call(lambda: fn(cfg_), warmup=1, iters=3)
         sites = pallas_call_sites(lambda: fn(cfg_))
-        iters = int(fn(cfg_)[1].iters)      # each config's own convergence
+        iters = int(fn(cfg_)[1].iters.max())  # each config's own convergence
         path_kind = "sweeps" if name == "sweeps" else "fused"
         modeled = modeled_estep_hbm_bytes(
             path_kind, b, v, k, l, iters, stream_bytes=stream,

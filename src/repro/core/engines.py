@@ -26,7 +26,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from functools import partial
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -192,6 +192,13 @@ def _incremental_core(cfg: LDAConfig, averaged: bool, state: EngineState,
     return state, res, eb
 
 
+class UpdateAux(NamedTuple):
+    """What an incremental update hands back besides the state and π."""
+
+    exp_elog_beta: jax.Array   # (V, K) Eφ the E-step ran against
+    sweeps: jax.Array          # (tiles,) int32 fixed-point sweeps per tile
+
+
 @partial(jax.jit, static_argnames=("cfg", "averaged", "pi_dtype"),
          donate_argnums=(2, 5))
 def incremental_update(cfg: LDAConfig, averaged: bool, state: EngineState,
@@ -207,13 +214,14 @@ def incremental_update(cfg: LDAConfig, averaged: bool, state: EngineState,
     the store's wire dtype: π is rounded through it before the add-new
     scatter so ⟨m_vk⟩ stays bit-consistent with the store's contents.
 
-    Returns (state, π_new (B, L, K), Eφ) — Eφ so γ-only stores can
-    snapshot the λ-epoch the E-step ran against.
+    Returns (state, π_new (B, L, K), ``UpdateAux``): Eφ, so γ-only stores
+    can snapshot the λ-epoch the E-step ran against, and the sweeps each
+    tile of the fixed point ran.
     """
     state, res, eb = _incremental_core(cfg, averaged, state, ids, cnts,
                                        old_pi, visited, num_words_total,
                                        pi_dtype)
-    return state, res.pi, eb
+    return state, res.pi, UpdateAux(eb, res.iters)
 
 
 def _csr_gather_flat(old_pi: jax.Array, ix: jax.Array) -> jax.Array:
@@ -270,7 +278,7 @@ def incremental_update_csr(cfg: LDAConfig, averaged: bool,
     state = dataclasses.replace(state, lam=lam, m_vk=m_vk, init_frac=frac,
                                 t=state.t + 1)
     new_pi = _csr_scatter_flat(res.pi, ix, b, w)
-    return state, new_pi, eb
+    return state, new_pi, UpdateAux(eb, res.iters)
 
 
 def _raw_memo_step(cfg: LDAConfig, averaged: bool, state: EngineState,
@@ -585,7 +593,7 @@ class LDAEngine:
             if g is not None:
                 tel.trace.end(g)
             s = tel.trace.begin("train/solve", width=width) if on else None
-            self.state, new_pi, eb = incremental_update(
+            self.state, new_pi, aux = incremental_update(
                 self.cfg, self.algo == "sivi", self.state, ids, cnts,
                 old_pi, visited, self.num_words_total,
                 self.memo.pi_wire_dtype)
@@ -593,7 +601,8 @@ class LDAEngine:
                 tel.trace.end(s, sync=self.state.lam)
             u = tel.trace.begin("train/memo_update", width=width) \
                 if on else None
-            self.memo = self.memo.update(rows, new_pi, exp_elog_beta=eb)
+            self.memo = self.memo.update(rows, new_pi,
+                                         exp_elog_beta=aux.exp_elog_beta)
             if u is not None:
                 tel.trace.end(u)
         else:
@@ -613,6 +622,7 @@ class LDAEngine:
                 m.set_gauge("train.memo_resident_bytes",
                             self.memo.footprint_bytes())
                 self._scatter_gauges(m, ids.shape)
+                self._sweep_gauges(m, aux.sweeps)
             wd = tel.watchdog
             if (self.algo in ("ivi", "sivi") and wd.enabled
                     and wd.should_check(self._updates)):
@@ -630,6 +640,15 @@ class LDAEngine:
         if steps is not None:
             m.set_gauge("train.scatter_dense_steps", steps[0])
             m.set_gauge("train.scatter_grid_steps", steps[1])
+
+    def _sweep_gauges(self, m, sweeps: jax.Array) -> None:
+        """``train.estep_sweeps``: the fixed-point sweeps this step ran,
+        summed over its tiles; ``train.estep_sweep_cap``: tiles ×
+        ``estep_max_iters``. Read after the step's λ sync, so the counts
+        are already on hand."""
+        m.set_gauge("train.estep_sweeps", int(np.asarray(sweeps).sum()))
+        m.set_gauge("train.estep_sweep_cap",
+                    sweeps.size * self.cfg.estep_max_iters)
 
     def _watchdog_armed(self) -> bool:
         """Whether the monotone-ELBO guarantee is in force: IVI (eq. 4 —
@@ -726,7 +745,7 @@ class LDAEngine:
                 tel.trace.end(g)
             ix = jnp.asarray(self._csr_flat_index(batch, width))
             s = tel.trace.begin("train/solve", width=width) if on else None
-            self.state, new_pi, eb = incremental_update_csr(
+            self.state, new_pi, aux = incremental_update_csr(
                 self.cfg, self.algo == "sivi", self.state, ids, cnts, segs,
                 ix, old_pi, visited, self.num_words_total,
                 self.memo.pi_wire_dtype)
@@ -735,7 +754,7 @@ class LDAEngine:
             u = tel.trace.begin("train/memo_update", width=width) \
                 if on else None
             self.memo = self.memo.update(rows, new_pi[:b_real],
-                                         exp_elog_beta=eb)
+                                         exp_elog_beta=aux.exp_elog_beta)
             if u is not None:
                 tel.trace.end(u)
         else:
@@ -752,6 +771,7 @@ class LDAEngine:
                 m.set_gauge("train.memo_resident_bytes",
                             self.memo.footprint_bytes())
                 self._scatter_gauges(m, ids.shape)
+                self._sweep_gauges(m, aux.sweeps)
             wd = tel.watchdog
             if (self.algo in ("ivi", "sivi") and wd.enabled
                     and wd.should_check(self._updates)):

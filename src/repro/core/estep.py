@@ -80,11 +80,14 @@ class EStepResult(NamedTuple):
     pi: jax.Array         # (B, L, K) token-aligned responsibilities
                           # (flat-token paths: (T, K))
     sstats: jax.Array     # (V, K) Σ_d Σ_l cnt·π scattered at token ids
-    iters: jax.Array      # () int32 fixed-point iterations used
+    iters: jax.Array      # (tiles,) int32 fixed-point sweeps each tile
+                          # ran: the B-tiles of the padded kernel; the jnp
+                          # backends and the CSR kernel run one tile
 
 
 def _fixed_point(cfg: LDAConfig, update_fn, gamma0: jax.Array):
-    """Run γ ← update(γ) until mean |Δγ| < tol or max_iters."""
+    """Run γ ← update(γ) until mean |Δγ| < tol or max_iters; the sweeps
+    run come back as one tile's (1,) count."""
 
     def cond(carry):
         _, delta, it = carry
@@ -98,7 +101,7 @@ def _fixed_point(cfg: LDAConfig, update_fn, gamma0: jax.Array):
 
     init = (gamma0, jnp.asarray(jnp.inf, gamma0.dtype), jnp.asarray(0, jnp.int32))
     gamma, _, iters = jax.lax.while_loop(cond, body, init)
-    return gamma, iters
+    return gamma, iters[None]
 
 
 def scatter_sstats(token_ids: jax.Array, weighted_pi: jax.Array,

@@ -3,13 +3,15 @@
 Production path (`ops.estep_pallas` / `ops.memo_correction_pallas`):
 
 * ``estep_fixed_point`` — the ENTIRE γ fixed point in one ``pallas_call``:
-  grid ``(B-tiles, max_iters, V-tiles)`` with γ, Eθ and the sweep
-  accumulator resident in VMEM scratch across grid steps. Each sweep
-  streams Eφ (and the dense counts C) HBM→VMEM once via the V grid axis;
-  a per-B-tile convergence flag in SMEM (mean |Δγ| ≤ tol) predicates the
-  remaining sweeps to no-ops, and the sweep counter is emitted per tile.
-  Nothing γ-shaped ever round-trips to HBM between sweeps — the old path
-  paid one pallas_call per sweep plus a jnp Eθ recomputation per sweep.
+  one grid step per B-tile, whose sweeps are a ``while`` loop inside the
+  kernel with γ, Eθ and the sweep accumulator in VMEM. C and Eφ stay in
+  HBM; each sweep copies them in by V-tile into two VMEM slots, the next
+  tile's copy running while the current one is reduced (a V-resident tile
+  is copied once). The loop ends when the tile's mean |Δγ| ≤ tol or at
+  ``max_iters``, so a converged tile streams nothing more, and the sweeps
+  each tile ran are emitted. Nothing γ-shaped ever round-trips to HBM
+  between sweeps — the old path paid one pallas_call per sweep plus a jnp
+  Eθ recomputation per sweep.
 * ``memo_delta`` — token-aligned π AND the subtract-old/add-new scatter as
   a **segment-sum** over two kernels: the token-π kernel tiles the (B, L)
   axes (the L grid axis — VMEM no longer bounds the corpus L) and forms
@@ -34,7 +36,7 @@ Tiling (DESIGN.md §7 and docs/estep.md): B-tile × V-tile × K — K is padded
 to a multiple of 128 by the wrapper (`ops.py`), V-tiles default to 512 and
 B-tiles to 128, so the fused fixed point's VMEM working set is
 
-    C (128·512) + Eφ (512·128) + γ/Eθ/acc (3·128·128)  ≈ 0.8 MB  « 48 MiB
+    2 × (C (128·512) + Eφ (512·128)) + γ/Eθ/acc (3·128·128) ≈ 1.2 MB « 48 MiB
 
 and every matmul hits the MXU with ≥128 on both the lane and the
 contraction dimension. ``stream_dtype=bfloat16`` streams C and Eφ in bf16
@@ -119,59 +121,90 @@ def _exp_elog_theta(g, k_real: int):
 # ---------------------------------------------------------------------------
 
 def _fixed_point_kernel(alpha0: float, tol: float, k_real: int,
-                        b_real: int, block_b: int, num_t: int, num_v: int,
-                        c_ref, eb_ref, g0_ref,
+                        b_real: int, block_b: int, block_v: int,
+                        tile_rows: int, num_t: int, num_v: int,
+                        c_hbm, eb_hbm, g0_ref,
                         gamma_ref, et_ref, iters_ref,
-                        gamma_s, et_s, acc_s, flags):
+                        c_buf, eb_buf, acc_s, sems):
+    """One B-tile's whole fixed point; γ and E[θ] live in the output blocks.
+
+    C and Eφ stay in HBM. Each sweep walks the V-tiles in order and copies
+    tile j+1 into the other half of a two-slot VMEM buffer while tile j is
+    reduced, so the sweeps stop streaming as soon as the tile converges.
+    A V-resident tile (``num_v == 1``) is copied once, before the first
+    sweep. ``tile_rows`` is the rows of one problem: vmapped problems
+    stacked along B (``estep_fixed_point``) each have their own ``b_real``.
+    """
     i = pl.program_id(0)
-    t = pl.program_id(1)
-    j = pl.program_id(2)
 
-    @pl.when((t == 0) & (j == 0))
-    def _start():
-        gamma_s[...] = g0_ref[...]
-        flags[0] = 0                                   # converged flag
-        flags[1] = 0                                   # sweeps run
+    def copies(j, slot):
+        return (pltpu.make_async_copy(
+                    c_hbm.at[pl.ds(i * block_b, block_b),
+                             pl.ds(j * block_v, block_v)],
+                    c_buf.at[slot], sems.at[0, slot]),
+                pltpu.make_async_copy(
+                    eb_hbm.at[pl.ds(j * block_v, block_v)],
+                    eb_buf.at[slot], sems.at[1, slot]))
 
-    live = flags[0] == 0
+    def fetch(j, slot):
+        for cp in copies(j, slot):
+            cp.start()
 
-    @pl.when(live & (j == 0))
-    def _sweep_start():
-        et_s[...] = _exp_elog_theta(gamma_s[...], k_real)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    def wait(j, slot):
+        for cp in copies(j, slot):
+            cp.wait()
 
-    @pl.when(live)
-    def _accumulate():
-        et = et_s[...]                                 # (bB, K)
-        eb = eb_ref[...].astype(jnp.float32)           # (bV, K)
+    resident = num_v == 1
+    if resident:
+        fetch(0, 0)
+        wait(0, 0)
+    gamma_ref[...] = g0_ref[...]
+    # mean |Δγ| over the tile's REAL rows/topics only — padding holds
+    # γ = α₀ exactly (zero diff) but must not dilute the convergence
+    # threshold, or the kernel stops earlier than the jnp backends
+    first_row = (i % (tile_rows // block_b)) * block_b
+    rows_real = jnp.clip(b_real - first_row, 1, block_b)
+
+    def accumulate(j, carry):
+        slot = 0 if resident else j % 2
+        if not resident:
+            @pl.when(j + 1 < num_v)
+            def _prefetch():
+                fetch(j + 1, 1 - slot)
+
+            wait(j, slot)
+        et = et_ref[...]                               # (bB, K)
+        eb = eb_buf[slot].astype(jnp.float32)          # (bV, K)
         p = jax.lax.dot_general(et, eb, (((1,), (1,)), ((), ())),
                                 precision=_F32,
                                 preferred_element_type=jnp.float32) + _EPS
-        r = c_ref[...].astype(jnp.float32) / p         # (bB, bV)
+        r = c_buf[slot].astype(jnp.float32) / p        # (bB, bV)
         acc_s[...] += jax.lax.dot(r, eb,
                                   precision=_F32,
                                   preferred_element_type=jnp.float32)
+        return carry
 
-    @pl.when(live & (j == num_v - 1))
-    def _sweep_end():
-        g_old = gamma_s[...]
+    def sweep(carry):
+        t, _ = carry
+        if not resident:
+            fetch(0, 0)
+        et_ref[...] = _exp_elog_theta(gamma_ref[...], k_real)
+        acc_s[...] = jnp.zeros_like(acc_s)
+        jax.lax.fori_loop(0, num_v, accumulate, 0)
+        g_old = gamma_ref[...]
         mask = jax.lax.broadcasted_iota(jnp.int32, g_old.shape, 1) < k_real
-        g_new = jnp.where(mask, alpha0 + et_s[...] * acc_s[...], alpha0)
-        # mean |Δγ| over the tile's REAL rows/topics only — padding holds
-        # γ = α₀ exactly (zero diff) but must not dilute the convergence
-        # threshold, or the kernel stops earlier than the jnp backends
-        rows_real = jnp.clip(b_real - i * block_b, 1, block_b)
+        g_new = jnp.where(mask, alpha0 + et_ref[...] * acc_s[...], alpha0)
         delta = jnp.abs(g_new - g_old).sum() / (rows_real * k_real)
-        gamma_s[...] = g_new
-        flags[1] += 1
-        flags[0] = jnp.where(delta <= tol, 1, 0).astype(jnp.int32)
+        gamma_ref[...] = g_new
+        return t + 1, jnp.where(delta <= tol, 1, 0).astype(jnp.int32)
 
-    @pl.when((t == num_t - 1) & (j == num_v - 1))
-    def _finish():
-        g = gamma_s[...]
-        gamma_ref[...] = g
-        et_ref[...] = _exp_elog_theta(g, k_real)
-        iters_ref[...] = jnp.full(iters_ref.shape, flags[1], jnp.int32)
+    def live(carry):
+        t, converged = carry
+        return (t < num_t) & (converged == 0)
+
+    t, _ = jax.lax.while_loop(live, sweep, (jnp.int32(0), jnp.int32(0)))
+    et_ref[...] = _exp_elog_theta(gamma_ref[...], k_real)
+    iters_ref[...] = jnp.full(iters_ref.shape, t, jnp.int32)
 
 
 def estep_fixed_point(c: jax.Array, eb: jax.Array, gamma0: jax.Array,
@@ -186,6 +219,10 @@ def estep_fixed_point(c: jax.Array, eb: jax.Array, gamma0: jax.Array,
     block grid; ``k_real``/``b_real`` mask the padded topic columns and
     batch rows out of the convergence mean. C/Eφ may be bf16 (fp32
     accumulation).
+
+    Under ``vmap`` over problems that share Eφ (D-IVI's workers), the
+    problems' rows are stacked into one call, each B-tile inside one
+    problem: Mosaic takes no batched operand left in HBM.
     """
     b, v = c.shape
     k = gamma0.shape[1]
@@ -193,40 +230,62 @@ def estep_fixed_point(c: jax.Array, eb: jax.Array, gamma0: jax.Array,
     block_b, block_v = min(block_b, b), min(block_v, v)
     assert b % block_b == 0 and v % block_v == 0, (b, v, block_b, block_v)
     interpret = _default_interpret(interpret)
-    nb, nv = b // block_b, v // block_v
-    grid = (nb, max(int(max_iters), 1), nv)
-    gamma, et, iters = pl.pallas_call(
-        functools.partial(_fixed_point_kernel, alpha0, tol, k_real,
-                          b_real, block_b, grid[1], nv),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, block_v), lambda i, t, j: (i, j)),
-            pl.BlockSpec((block_v, k), lambda i, t, j: (j, 0)),
-            pl.BlockSpec((block_b, k), lambda i, t, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, k), lambda i, t, j: (i, 0)),
-            pl.BlockSpec((block_b, k), lambda i, t, j: (i, 0)),
-            # one (1, 1) block per B-tile: with the tile axis squeezed the
-            # block equals the array's last two dims, which Mosaic's (8, 128)
-            # tiling rule accepts (a (1, 1) block of an (nb, 1) array is not)
-            pl.BlockSpec((None, 1, 1), lambda i, t, j: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((nb, 1, 1), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_b, k), jnp.float32),
-            pltpu.VMEM((block_b, k), jnp.float32),
-            pltpu.VMEM((block_b, k), jnp.float32),
-            pltpu.SMEM((2,), jnp.int32),
-        ],
-        compiler_params=_PARAMS,
-        interpret=interpret,
-    )(c, eb, gamma0)
-    return gamma, et, iters.reshape(nb, 1)
+    nv = v // block_v
+    nbuf = 1 if nv == 1 else 2
+    kernel = functools.partial(_fixed_point_kernel, alpha0, tol, k_real,
+                               b_real, block_b, block_v, b,
+                               max(int(max_iters), 1), nv)
+
+    @jax.custom_batching.custom_vmap
+    def solve(c, eb, gamma0):
+        nb = c.shape[0] // block_b
+        return pl.pallas_call(
+            kernel,
+            grid=(nb,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((block_b, k), lambda i: (i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_b, k), lambda i: (i, 0)),
+                pl.BlockSpec((block_b, k), lambda i: (i, 0)),
+                # one (1, 1) block per B-tile: with the tile axis squeezed
+                # the block equals the array's last two dims, which Mosaic's
+                # (8, 128) tiling rule accepts (a (1, 1) block of an (nb, 1)
+                # array is not)
+                pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((nb * block_b, k), jnp.float32),
+                jax.ShapeDtypeStruct((nb * block_b, k), jnp.float32),
+                jax.ShapeDtypeStruct((nb, 1, 1), jnp.int32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((nbuf, block_b, block_v), c.dtype),
+                pltpu.VMEM((nbuf, block_v, k), eb.dtype),
+                pltpu.VMEM((block_b, k), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, nbuf)),
+            ],
+            compiler_params=_PARAMS,
+            interpret=interpret,
+        )(c, eb, gamma0)
+
+    @solve.def_vmap
+    def _stack_problems(n, batched, c, eb, gamma0):
+        if batched[1]:
+            raise NotImplementedError("vmap of the padded fixed point over "
+                                      "Eφ: the problems must share it")
+        c = c if batched[0] else jnp.broadcast_to(c, (n, b, v))
+        gamma0 = gamma0 if batched[2] else jnp.broadcast_to(gamma0,
+                                                            (n, b, k))
+        gamma, et, iters = solve(c.reshape(n * b, v), eb,
+                                 gamma0.reshape(n * b, k))
+        return ((gamma.reshape(n, b, k), et.reshape(n, b, k),
+                 iters.reshape(n, b // block_b, 1, 1)), (True,) * 3)
+
+    gamma, et, iters = solve(c, eb, gamma0)
+    return gamma, et, iters.reshape(b // block_b, 1)
 
 
 # ---------------------------------------------------------------------------

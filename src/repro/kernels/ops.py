@@ -102,10 +102,9 @@ def _pad_rows(x: jax.Array, rows: int) -> jax.Array:
     return jnp.pad(x, pad)
 
 
-# Eφ blocks at or under this size are made V-resident: one V tile, so the
-# Pallas pipeline fetches Eφ once per call and C once per B-tile instead of
-# re-streaming both every sweep (the block index never changes across the
-# sweep axis). Chosen well under the 16 MB VMEM with the fp32 working set.
+# Eφ blocks at or under this size are made V-resident: one V tile, which the
+# fixed point copies into VMEM once per B-tile instead of re-streaming C and
+# Eφ every sweep. Chosen well under the 16 MB VMEM with the fp32 working set.
 _V_RESIDENT_BYTES = 6 * 1024 * 1024
 
 
@@ -117,7 +116,7 @@ def effective_fixed_point_blocks(b: int, v: int, k: int, *,
 
     ``_run_fixed_point`` promotes ``block_v`` to whole-V whenever the
     lane-aligned Eφ block fits the resident budget — one V tile means the
-    pipeline fetches Eφ once per call instead of once per sweep. The
+    kernel copies Eφ once per B-tile instead of once per sweep. The
     promotion used to be silent; this mirror of ``csr_effective_block_t``
     exposes it so tune records, the roofline HBM model, and telemetry
     report the tile that ran, never a requested-but-ignored ``block_v``.
@@ -161,7 +160,8 @@ def correction_scatter_steps(cfg: LDAConfig, token_shape: Tuple[int, ...], *,
 def _run_fixed_point(cfg: LDAConfig, exp_elog_beta: jax.Array,
                      token_ids: jax.Array, counts: jax.Array,
                      gamma0: Optional[jax.Array], block_b: int, block_v: int):
-    """densify → pad → fused fixed-point kernel. Returns real-shape γ/Eθ."""
+    """densify → pad → fused fixed-point kernel. Returns real-shape γ/Eθ
+    and the sweeps each B-tile ran, (nb,)."""
     bsz = token_ids.shape[0]
     v = exp_elog_beta.shape[0]
     stream_bytes = 2 if cfg.estep_stream_dtype == "bfloat16" else 4
@@ -183,7 +183,7 @@ def _run_fixed_point(cfg: LDAConfig, exp_elog_beta: jax.Array,
         _stream_cast(cfg, cpad), _stream_cast(cfg, ebpad), gpad,
         cfg.alpha0, cfg.estep_tol, cfg.estep_max_iters, k_real=k,
         b_real=bsz, block_b=block_b, block_v=block_v)
-    return gamma[:bsz, :k], et[:bsz, :k], iters.max()
+    return gamma[:bsz, :k], et[:bsz, :k], iters.reshape(-1)
 
 
 @partial(jax.jit, static_argnames=("cfg", "policy", "block_b", "block_v",
@@ -319,7 +319,8 @@ def _run_fixed_point_csr(cfg: LDAConfig, exp_elog_beta: jax.Array,
         counts, segments, _stream_cast(cfg, eb_tok), gpad,
         cfg.alpha0, cfg.estep_tol, cfg.estep_max_iters, k_real=k,
         b_real=num_docs, block_t=block_t)
-    return gamma[:num_docs, :k], et[:num_docs, :k], eb_tok, iters.max()
+    return (gamma[:num_docs, :k], et[:num_docs, :k], eb_tok,
+            iters.reshape(-1))
 
 
 @partial(jax.jit, static_argnames=("cfg", "num_docs", "policy", "block_t",
@@ -457,7 +458,8 @@ def estep_pallas_sweeps(cfg: LDAConfig, exp_elog_beta: jax.Array,
     p_tok = jnp.einsum("bk,blk->bl", etheta, ebg) + _EPS
     pi = etheta[:, None, :] * ebg / p_tok[:, :, None]
     pi = jnp.where(counts[:, :, None] > 0, pi, 0.0)
-    return EStepResult(gamma=gamma, pi=pi, sstats=sstats_out, iters=iters)
+    return EStepResult(gamma=gamma, pi=pi, sstats=sstats_out,
+                       iters=iters[None])
 
 
 def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,

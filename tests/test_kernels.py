@@ -7,13 +7,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import LDAConfig
-from repro.core.estep import estep_dense
+from repro.core.estep import densify, estep_dense, estep_gather
 from repro.core.math import exp_dirichlet_expectation
 from repro.data import PAPER_CORPORA, make_corpus
 from repro.kernels import lda_estep, ref
-from repro.kernels.ops import estep_pallas
+from repro.kernels.ops import (estep_pallas, memo_correction_pallas,
+                               pad_inputs)
 
 
 SHAPES = [
@@ -82,6 +84,123 @@ def test_estep_pallas_full_path():
     np.testing.assert_allclose(r1.gamma, r2.gamma, rtol=1e-3, atol=1e-3)
     np.testing.assert_allclose(r1.sstats, r2.sstats, rtol=1e-2, atol=1e-3)
     np.testing.assert_allclose(r1.pi, r2.pi, rtol=1e-3, atol=1e-4)
+
+
+def _fixed_point_per_tile(c, eb, g0, alpha0, tol, max_iters, k_real,
+                          b_real, block_b, block_v):
+    """The fused fixed point's update, one B-tile at a time in jnp: the
+    kernel's own E[θ]/ψ, the V-tiles summed in ascending order, the same
+    mean |Δγ| over the tile's real rows. Returns (γ, E[θ], sweeps)."""
+    c, eb = c.astype(jnp.float32), eb.astype(jnp.float32)
+    real = jnp.arange(g0.shape[1])[None, :] < k_real
+    gammas, sweeps = [], []
+    for i in range(c.shape[0] // block_b):
+        rows = slice(i * block_b, (i + 1) * block_b)
+        rows_real = min(max(b_real - i * block_b, 1), block_b)
+        g, t = g0[rows], 0
+        while t < max_iters:
+            et = lda_estep._exp_elog_theta(g, k_real)
+            acc = jnp.zeros_like(g)
+            for j in range(0, c.shape[1], block_v):
+                ebj = eb[j:j + block_v]
+                p = jax.lax.dot_general(
+                    et, ebj, (((1,), (1,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST) + 1e-30
+                acc = acc + jax.lax.dot(c[rows, j:j + block_v] / p, ebj,
+                                        precision=jax.lax.Precision.HIGHEST)
+            g_new = jnp.where(real, alpha0 + et * acc, alpha0)
+            delta = float(jnp.abs(g_new - g).sum()) / (rows_real * k_real)
+            g, t = g_new, t + 1
+            if delta <= tol:
+                break
+        gammas.append(g)
+        sweeps.append(t)
+    gamma = jnp.concatenate(gammas)
+    return gamma, lda_estep._exp_elog_theta(gamma, k_real), sweeps
+
+
+def _tiles_batch(b=20, v=300, k=8, l=24, seed=0):
+    """Three 8-document tiles that stop at different sweeps: tile 0 starts
+    at its fixed point, tile 1 cold, tile 2 cold with 20× longer documents
+    (it needs more than 40 sweeps)."""
+    rng = np.random.default_rng(seed)
+    ids = jnp.asarray(np.stack([rng.choice(v, l, replace=False)
+                                for _ in range(b)]).astype(np.int32))
+    cnts = rng.integers(1, 4, (b, l)).astype(np.float32)
+    cnts[16:] *= 20
+    cnts = jnp.asarray(cnts)
+    lam = jax.random.gamma(jax.random.key(seed), 0.2, (v, k)) * 5 + 0.01
+    eb = exp_dirichlet_expectation(lam, axis=0)
+    cfg = LDAConfig(num_topics=k, vocab_size=v, estep_tol=1e-3,
+                    estep_max_iters=40)
+    settled = estep_gather(dataclasses.replace(cfg, estep_tol=0.0,
+                                               estep_max_iters=200),
+                           eb, ids[:8], cnts[:8]).gamma
+    g0 = jnp.full((b, k), cfg.alpha0 + 1.0).at[:8].set(settled)
+    return cfg, eb, ids, cnts, g0
+
+
+@pytest.mark.parametrize("block_v,stream,workers", [
+    (128, jnp.float32, None),      # V 384 in 3 tiles: streamed, two slots
+    (384, jnp.float32, None),      # one V-resident tile, copied once
+    (128, jnp.bfloat16, None),     # bf16 C and Eφ, fp32 accumulation
+    (128, jnp.float32, 2),         # memo correction vmapped over workers
+], ids=["streamed", "resident", "streamed-bf16", "vmap-workers"])
+def test_fixed_point_stops_per_tile(block_v, stream, workers):
+    """Each B-tile runs its own sweeps and stops when it converges: γ, E[θ]
+    and the sweep counts are those of the per-tile jnp loop (to fp32
+    rounding), γ is ``estep_gather``'s on the tile's documents, and a tile
+    that hits ``max_iters`` stops there."""
+    cfg, eb, ids, cnts, g0 = _tiles_batch()
+    b, k = g0.shape
+    block_b = 8
+    cpad, ebpad, _ = pad_inputs(densify(ids, cnts, cfg.vocab_size), eb,
+                                block_b, block_v)
+    cpad, ebpad = cpad.astype(stream), ebpad.astype(stream)
+    gpad = jnp.pad(g0, ((0, cpad.shape[0] - b), (0, ebpad.shape[1] - k)),
+                   constant_values=cfg.alpha0)
+    args = (cpad, ebpad, gpad, cfg.alpha0, cfg.estep_tol,
+            cfg.estep_max_iters, k, b)
+    gamma, et, iters = lda_estep.estep_fixed_point(
+        *args, block_b=block_b, block_v=block_v)
+    # the TPU interpreter runs each copy only when it is waited on: a
+    # V-tile read before its wait would read what the slot held before
+    late = lda_estep.estep_fixed_point(
+        *args, block_b=block_b, block_v=block_v,
+        interpret=pltpu.InterpretParams(dma_execution_mode="on_wait"))
+    for got, want in zip(late, (gamma, et, iters)):
+        np.testing.assert_array_equal(got, want)
+    want_g, want_et, want_sweeps = _fixed_point_per_tile(
+        *args, block_b, block_v)
+    assert np.asarray(iters).ravel().tolist() == want_sweeps
+    assert want_sweeps[0] < want_sweeps[1] < cfg.estep_max_iters
+    assert want_sweeps[2] == cfg.estep_max_iters
+    np.testing.assert_allclose(gamma, want_g, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(et, want_et, rtol=1e-5, atol=1e-7)
+    if stream == jnp.float32:
+        for i in range(3):
+            rows = slice(i * block_b, min((i + 1) * block_b, b))
+            ref_g = estep_gather(cfg, eb, ids[rows], cnts[rows],
+                                 g0[rows]).gamma
+            np.testing.assert_allclose(gamma[rows, :k], ref_g, rtol=2e-3,
+                                       atol=2e-3)
+    if workers:
+        # D-IVI's workers share Eφ: vmap stacks their rows into one call
+        ids_w = jnp.stack([ids, ids[::-1]])
+        cnts_w = jnp.stack([cnts, cnts[::-1]])
+        old_pi = jnp.zeros(ids_w.shape + (k,), jnp.float32)
+        visited = jnp.zeros((workers, b), bool)
+
+        def correction(i, c, o, vis):
+            return memo_correction_pallas(cfg, eb, i, c, o, vis,
+                                          block_b=block_b, block_v=block_v)
+        batched = jax.vmap(correction)(ids_w, cnts_w, old_pi, visited)
+        for w in range(workers):
+            one = correction(ids_w[w], cnts_w[w], old_pi[w], visited[w])
+            for got, want in zip(jax.tree_util.tree_leaves(batched),
+                                 jax.tree_util.tree_leaves(one)):
+                np.testing.assert_allclose(got[w], want, rtol=1e-6,
+                                           atol=1e-6)
 
 
 def _memo_delta_refs(ids, cnts, ebt, et, v):

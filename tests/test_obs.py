@@ -391,6 +391,38 @@ def test_scatter_step_gauges(tiny_corpus, backend, layout, block_v, steps):
     assert got == (steps or (None, None))
 
 
+@pytest.mark.parametrize("backend,layout,block_b,tiles", [
+    ("pallas", "padded", 8, 2),           # 16 docs in two B-tiles
+    ("gather", "padded", None, 1),        # the jnp fixed point: one tile
+    ("csr", "csr", None, 1),              # the flat kernel: one tile
+])
+def test_estep_sweep_gauges(tiny_corpus, backend, layout, block_b, tiles):
+    """An IVI step records the fixed-point sweeps it ran, summed over its
+    tiles, against tiles × ``estep_max_iters``."""
+    from repro.core.types import KernelPolicy
+    from repro.data.stream import as_doc_stream
+    corpus, spec = tiny_corpus
+    cfg = LDAConfig(num_topics=4, vocab_size=spec.vocab_size,
+                    estep_max_iters=5, estep_backend=backend,
+                    kernel_policy=(KernelPolicy(block_b=block_b)
+                                   if block_b else None))
+    tel = Telemetry()
+    if layout == "csr":
+        eng = LDAEngine(cfg, as_doc_stream(corpus), algo="ivi",
+                        batch_size=16, seed=0, telemetry=tel, layout="csr",
+                        memo_store="chunked")
+        assert eng.stream_step()
+    else:
+        eng = LDAEngine(cfg, corpus, algo="ivi", batch_size=16, seed=0,
+                        telemetry=tel)
+        eng.run_minibatch(np.arange(16))
+    gauges = {g["name"]: g["value"]
+              for g in tel.metrics.snapshot()["gauges"]}
+    cap = gauges["train.estep_sweep_cap"]
+    assert cap == tiles * cfg.estep_max_iters
+    assert tiles <= gauges["train.estep_sweeps"] <= cap
+
+
 def test_watchdog_catches_real_bound_decrease(tiny_corpus):
     """Corrupting the memo mid-run breaks eq. 4's subtract-old bookkeeping —
     exactly the failure class the watchdog exists for — and the next armed

@@ -100,8 +100,11 @@ def test_fixed_point_compiles(spec, stream):
     def fn(c, eb, g0):
         return lda_estep.estep_fixed_point(c, eb, g0, 0.5, 1e-4, 100, K, B,
                                            interpret=False)
-    _assert_mosaic(fn, spec((B, V_PAD), stream), spec((V_PAD, K_PAD), stream),
-                   spec((B, K_PAD)))
+    compiled = _assert_mosaic(fn, spec((B, V_PAD), stream),
+                              spec((V_PAD, K_PAD), stream), spec((B, K_PAD)))
+    # the trace, and so estep_roofline.padded, find the call by this name
+    assert [name for name, _ in _kernel_calls(compiled)] == [
+        "_fixed_point_kernel"]
 
 
 @pytest.mark.parametrize("stream,block_t", [(jnp.float32, T),

@@ -1,4 +1,4 @@
-"""Benchmark helpers: timing, CSV emission, shared LDA setup.
+"""Benchmark helpers: the shared LDA setup of the paper scripts.
 
 The paper benchmarks build their estimators through the ``repro.lda.LDA``
 facade (`make_lda` below) — the same public surface users drive — so a
@@ -7,10 +7,7 @@ tests.
 """
 from __future__ import annotations
 
-import time
-from typing import Callable, Iterable, List, Tuple
-
-import jax
+from typing import Tuple
 
 
 def paper_setup(corpus_name: str, *, estep_iters: int = 60, seed: int = 0):
@@ -39,25 +36,3 @@ def make_lda(corpus_name: str, *, algo: str = "ivi", batch: int = 32,
     lda.partial_fit(train, steps=0,
                     test_corpus=test if with_test else None)
     return lda, train, test
-
-
-def time_call(fn: Callable, *args, warmup: int = 1, iters: int = 5) -> float:
-    """Median wall-time per call in microseconds (blocks on jax outputs)."""
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return times[len(times) // 2] * 1e6
-
-
-def emit(rows: Iterable[tuple]) -> List[str]:
-    out = []
-    for name, us, derived in rows:
-        line = f"{name},{us:.1f},{derived}"
-        print(line)
-        out.append(line)
-    return out

@@ -1,16 +1,17 @@
 """Benchmark driver — one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV. Budget-friendly defaults (the
-paper-scale corpora are sampled down per DESIGN.md §8); pass --full for the
-larger synthetic corpora.
+paper-scale corpora are sampled down); ``--corpus`` picks another of the
+synthetic corpora.
 
 Sections:
   fig1   — MVI/SVI/IVI/S-IVI convergence (paper Fig. 1)
   fig2   — IVI mini-batch size sweep (paper Fig. 2)
   table2 — D-IVI LPP + time vs processors × batch (paper Table 2 / Fig. 3)
   fig5   — delay robustness (paper Fig. 5)
-  kernel — E-step hotspot micro-benchmarks
-  roofline — dry-run roofline summary (reads results/dryrun.jsonl)
+
+These are CPU-scale reproductions of the paper's curves; speed is measured
+on the chip by ``bench/run.py``.
 """
 from __future__ import annotations
 
@@ -29,7 +30,7 @@ def main() -> None:
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     from benchmarks import (fig1_convergence, fig2_minibatch, fig5_delays,
-                            kernel_bench, roofline, table2_divi)
+                            table2_divi)
     sections = {
         "fig1": lambda: (fig1_convergence.rows(args.corpus)
                          # K = K* regime (paper-consistent final ordering)
@@ -37,8 +38,6 @@ def main() -> None:
         "fig2": lambda: fig2_minibatch.rows(args.corpus),
         "table2": lambda: table2_divi.rows(args.corpus),
         "fig5": lambda: fig5_delays.rows(args.corpus),
-        "kernel": kernel_bench.rows,
-        "roofline": roofline.rows,
     }
     chosen = (args.only.split(",") if args.only else list(sections))
     print("name,us_per_call,derived")

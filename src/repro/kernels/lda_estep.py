@@ -24,13 +24,8 @@ Production path (`ops.estep_pallas` / `ops.memo_correction_pallas`):
   pairs that meet, so a row tile no longer meets every chunk. No dense (nb, V, K) one-hot partials exist anywhere, and the
   IVI correction still needs **no (B, L, K) jnp intermediates**: the only
   (B, L, K) array XLA sees is the Eφ token gather feeding the kernel.
-  The retired one-hot-partial formulation is kept as
-  ``memo_delta_onehot`` (benchmark baseline).
-
-Legacy per-sweep path
----------------------
-* ``estep_sweep``  — γ' = α₀ + Eθ ⊙ (R·Eφ),  R = C ⊘ (Eθ·Eφᵀ + ε)
-* ``sstats``       — S  = Eφ ⊙ (Rᵀ·Eθ)
+* ``estep_fixed_point_csr`` / ``memo_delta_csr`` — the same pair over a
+  flat (T,) token stream (the CSR layout).
 
 Tiling (DESIGN.md §7 and docs/estep.md): B-tile × V-tile × K — K is padded
 to a multiple of 128 by the wrapper (`ops.py`), V-tiles default to 512 and
@@ -565,8 +560,7 @@ def memo_delta(token_ids: jax.Array, counts: jax.Array, eb_tok: jax.Array,
     steps in place of ``chunks × row_tiles`` (``scatter_grid_steps``). The
     returned π stays in document order; the masses differ from a scatter
     in document order only by the fp32 summation order within a word's
-    rows. The retired partial formulation is kept as
-    ``memo_delta_onehot`` (benchmark baseline).
+    rows.
 
     B must divide by the effective B-tile (pad upstream with zero-count
     rows); V and L are padded here (zero-count padding is inert).
@@ -839,245 +833,3 @@ def memo_delta_csr(token_ids: jax.Array, counts: jax.Array,
                               block_t, interpret)
     pi = pi_pad if tp == t else pi_pad[:t]
     return (pi, *masses)
-
-
-# ---------------------------------------------------------------------------
-# legacy one-hot memo-correction kernel (benchmark baseline)
-# ---------------------------------------------------------------------------
-
-def _memo_delta_onehot_kernel(block_v: int, has_old: bool, quantize: bool,
-                              *refs):
-    if has_old:
-        (ids_ref, cnts_ref, ebtok_ref, oldpi_ref, et_ref,
-         pi_ref, snew_ref, sold_ref) = refs
-    else:
-        ids_ref, cnts_ref, ebtok_ref, et_ref, pi_ref, snew_ref = refs
-        oldpi_ref = sold_ref = None
-    j = pl.program_id(1)
-    cnts = cnts_ref[...]                               # (bB, L)
-
-    @pl.when(j == 0)
-    def _pi():
-        et = et_ref[...]                               # (bB, K)
-        ebt = ebtok_ref[...]                           # (bB, L, K)
-        p = (et[:, None, :] * ebt).sum(-1) + _EPS      # (bB, L)
-        pi = et[:, None, :] * ebt / p[:, :, None]
-        pi = jnp.where(cnts[:, :, None] > 0, pi, 0.0)
-        if quantize:
-            # round through the memo store's wire dtype BEFORE scattering,
-            # so ⟨m_vk⟩ adds exactly what the store will later subtract
-            pi = pi.astype(jnp.bfloat16).astype(jnp.float32)
-        pi_ref[...] = pi
-
-    bb, ll, kk = pi_ref.shape
-    ids_flat = ids_ref[...].reshape(1, bb * ll)
-    rows = j * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (block_v, bb * ll), 0)
-    onehot = (rows == ids_flat).astype(jnp.float32)    # (bV, bB·L)
-
-    # Each (nb, V-tile) partial block is visited exactly once, so a plain
-    # write is safe on TPU — accumulating (V, K) blocks across B-tiles is
-    # not, because the B axis is the OUTER grid axis here (π pins it) and
-    # Pallas only defines revisited output blocks for consecutive revisits.
-    w_new = (cnts[:, :, None] * pi_ref[...]).reshape(bb * ll, kk)
-    snew_ref[...] = jax.lax.dot(onehot, w_new,
-                                preferred_element_type=jnp.float32)[None]
-
-    if has_old:
-        w_old = (cnts[:, :, None] * oldpi_ref[...]).reshape(bb * ll, kk)
-        sold_ref[...] = jax.lax.dot(onehot, w_old,
-                                    preferred_element_type=jnp.float32)[None]
-
-
-# VMEM budget for one one-hot memo_delta grid step (≈4 (block_b, L, K) fp32
-# cubes plus the (block_v, block_b·L) one-hot), kept at a sixth of the
-# 48 MiB scoped limit to leave room for double buffering. The wrapper
-# halves block_b until the step fits; the L axis is NOT tiled here, which
-# is the L ≤ ~4k cap the segment-sum path removes.
-_DELTA_VMEM_BUDGET = 8 * 1024 * 1024
-
-
-def delta_effective_block_b(b: int, l: int, k: int, *, block_b: int = 32,
-                            block_v: int = 128, has_old: bool = True) -> int:
-    """The B-tile ``memo_delta_onehot`` actually runs after the VMEM guard.
-
-    Larger B-tiles mean fewer (nb, V, K) partial blocks to spill and
-    reduce, so the default starts at 32 and is halved until the per-step
-    working set fits ``_DELTA_VMEM_BUDGET`` (e.g. L=128, K=128 lands on
-    16; L=512 on 4). Exposed so the BENCH_estep HBM model can count the
-    same grid the kernel uses.
-    """
-    block_b = min(block_b, b)
-    ncubes = 4 if has_old else 3
-
-    def _step_bytes(bb):
-        return (ncubes * bb * l * k + block_v * bb * l) * 4
-
-    while block_b > 1 and _step_bytes(block_b) > _DELTA_VMEM_BUDGET:
-        nxt = block_b // 2
-        block_b = nxt if b % nxt == 0 else 1   # keep the grid exact
-    return block_b
-
-
-def memo_delta_onehot(token_ids: jax.Array, counts: jax.Array,
-                      eb_tok: jax.Array, etheta: jax.Array, vocab_size: int,
-                      old_pi: jax.Array | None = None, *,
-                      quantize: bool = False, block_b: int = 32,
-                      block_v: int = 128, interpret: bool | None = None):
-    """RETIRED production path, kept as the benchmark baseline.
-
-    Same contract as ``memo_delta``, via the dense one-hot formulation: one
-    kernel forms π and scatters cnt·π_new / cnt·π_old with a one-hot MXU
-    matmul into per-B-tile (nb, V, K) partials (each output block written
-    exactly once — the TPU-safe revisit discipline), reduced over nb in
-    jnp here. Those partials are the cost the segment-sum path removes:
-    ~2·nb·V·K fp32 of transient HBM per batch (~2.5 GB at Arxiv V=142k),
-    and with the L axis untiled the VMEM guard caps L at ~4k (K=128).
-
-    B must divide by ``block_b`` (pad upstream; ``block_b`` is halved
-    automatically until the VMEM step budget holds, see
-    ``_DELTA_VMEM_BUDGET``); V is padded here (ids are always < V so the
-    padded rows are zero and stripped).
-    """
-    b, l = token_ids.shape
-    k = etheta.shape[1]
-    has_old = old_pi is not None
-    block_b = delta_effective_block_b(b, l, k, block_b=block_b,
-                                      block_v=block_v, has_old=has_old)
-    assert b % block_b == 0, (b, block_b)
-    interpret = _default_interpret(interpret)
-    vp = ((vocab_size + block_v - 1) // block_v) * block_v
-    nb, nv = b // block_b, vp // block_v
-
-    row_spec = pl.BlockSpec((block_b, l), lambda i, j: (i, 0))
-    cube_spec = pl.BlockSpec((block_b, l, k), lambda i, j: (i, 0, 0))
-    part_spec = pl.BlockSpec((1, block_v, k), lambda i, j: (i, j, 0))
-    in_specs = [row_spec, row_spec, cube_spec]
-    inputs = [token_ids, counts, eb_tok]
-    if has_old:
-        in_specs.append(cube_spec)
-        inputs.append(old_pi)
-    in_specs.append(pl.BlockSpec((block_b, k), lambda i, j: (i, 0)))
-    inputs.append(etheta)
-    out_specs = [cube_spec, part_spec]
-    out_shape = [jax.ShapeDtypeStruct((b, l, k), jnp.float32),
-                 jax.ShapeDtypeStruct((nb, vp, k), jnp.float32)]
-    if has_old:
-        out_specs.append(part_spec)
-        out_shape.append(jax.ShapeDtypeStruct((nb, vp, k), jnp.float32))
-
-    outs = pl.pallas_call(
-        functools.partial(_memo_delta_onehot_kernel, block_v, has_old,
-                          quantize),
-        grid=(nb, nv),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*inputs)
-    pi, snew = outs[0], outs[1].sum(0)[:vocab_size]
-    if has_old:
-        return pi, snew, outs[2].sum(0)[:vocab_size]
-    return pi, snew
-
-
-# ---------------------------------------------------------------------------
-# legacy γ-sweep kernel (one pallas_call per sweep)
-# ---------------------------------------------------------------------------
-
-def _sweep_kernel(alpha0: float, num_v_tiles: int,
-                  c_ref, et_ref, eb_ref, out_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    et = et_ref[...]                                       # (bB, K)
-    eb = eb_ref[...]                                       # (bV, K)
-    p = jax.lax.dot_general(et, eb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) + _EPS
-    r = c_ref[...] / p                                     # (bB, bV)
-    out_ref[...] += jax.lax.dot(r, eb,
-                                preferred_element_type=jnp.float32)
-
-    @pl.when(j == num_v_tiles - 1)
-    def _fin():
-        out_ref[...] = alpha0 + et * out_ref[...]
-
-
-def estep_sweep(c: jax.Array, etheta: jax.Array, eb: jax.Array,
-                alpha0: float, *, block_b: int = 128, block_v: int = 512,
-                interpret: bool | None = None) -> jax.Array:
-    """One fixed-point sweep γ' = α₀ + Eθ ⊙ ((C ⊘ Eθ·Eφᵀ)·Eφ).
-
-    Shapes: c (B, V), etheta (B, K), eb (V, K) → (B, K).
-    B, V, K must already be padded to the block grid (see ops.py).
-    """
-    b, v = c.shape
-    k = etheta.shape[1]
-    block_b, block_v = min(block_b, b), min(block_v, v)
-    assert b % block_b == 0 and v % block_v == 0, (b, v, block_b, block_v)
-    interpret = _default_interpret(interpret)
-    grid = (b // block_b, v // block_v)
-    return pl.pallas_call(
-        functools.partial(_sweep_kernel, alpha0, grid[1]),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, block_v), lambda i, j: (i, j)),
-            pl.BlockSpec((block_b, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_v, k), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, k), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, k), jnp.float32),
-        interpret=interpret,
-    )(c, etheta, eb)
-
-
-# ---------------------------------------------------------------------------
-# sufficient-statistics kernel
-# ---------------------------------------------------------------------------
-
-def _sstats_kernel(num_b_tiles: int, c_ref, et_ref, eb_ref, out_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    et = et_ref[...]                                       # (bB, K)
-    eb = eb_ref[...]                                       # (bV, K)
-    p = jax.lax.dot_general(et, eb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) + _EPS
-    r = c_ref[...] / p                                     # (bB, bV)
-    out_ref[...] += jax.lax.dot_general(
-        r, et, (((0,), (0,)), ((), ())),                   # Rᵀ·Eθ → (bV, K)
-        preferred_element_type=jnp.float32)
-
-    @pl.when(j == num_b_tiles - 1)
-    def _fin():
-        out_ref[...] *= eb
-
-
-def sstats(c: jax.Array, etheta: jax.Array, eb: jax.Array, *,
-           block_b: int = 128, block_v: int = 512,
-           interpret: bool | None = None) -> jax.Array:
-    """Expected topic-word counts S = Eφ ⊙ (Rᵀ·Eθ) → (V, K)."""
-    b, v = c.shape
-    k = etheta.shape[1]
-    block_b, block_v = min(block_b, b), min(block_v, v)
-    assert b % block_b == 0 and v % block_v == 0, (b, v, block_b, block_v)
-    interpret = _default_interpret(interpret)
-    grid = (v // block_v, b // block_b)                    # B-axis innermost
-    return pl.pallas_call(
-        functools.partial(_sstats_kernel, grid[1]),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, block_v), lambda i, j: (j, i)),
-            pl.BlockSpec((block_b, k), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_v, k), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_v, k), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((v, k), jnp.float32),
-        interpret=interpret,
-    )(c, etheta, eb)

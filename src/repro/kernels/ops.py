@@ -14,11 +14,6 @@ partials.
 ``memo_correction_pallas`` is the IVI hot path behind
 ``core.estep.PallasBackend.solve_correction``: the same three launches
 also emit the subtract-old/add-new correction ``S_new − S_old`` directly.
-
-``estep_pallas_sweeps`` keeps the pre-fusion formulation (one
-``pallas_call`` per sweep inside ``lax.while_loop`` + a separate sstats
-kernel + jnp π recovery) as the benchmark baseline — see
-``benchmarks/kernel_bench.py`` and BENCH_estep.json.
 """
 from __future__ import annotations
 
@@ -31,12 +26,9 @@ import jax.numpy as jnp
 from repro.core.estep import (CSRTokenBatch, EStepResult, densify,
                               segment_sum_docs, warm_start_gamma,
                               warm_start_gamma_flat)
-from repro.core.math import exp_dirichlet_expectation
 from repro.core.types import DEFAULT_KERNEL_POLICY, KernelPolicy, LDAConfig
 from repro.kernels import lda_estep
 from repro.kernels.flash_attention import flash_attention
-
-_EPS = 1e-30  # fp32-safe (1e-100 underflows to 0)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -104,7 +96,9 @@ def _pad_rows(x: jax.Array, rows: int) -> jax.Array:
 
 # Eφ blocks at or under this size are made V-resident: one V tile, which the
 # fixed point copies into VMEM once per B-tile instead of re-streaming C and
-# Eφ every sweep. Chosen well under the 16 MB VMEM with the fp32 working set.
+# Eφ every sweep. Chosen well under the kernels' 48 MiB scoped VMEM limit
+# (``lda_estep._PARAMS``), which also holds the double-buffered C tiles, the
+# fp32 working set and the bf16 operand splits of the HIGHEST contractions.
 _V_RESIDENT_BYTES = 6 * 1024 * 1024
 
 
@@ -118,8 +112,8 @@ def effective_fixed_point_blocks(b: int, v: int, k: int, *,
     lane-aligned Eφ block fits the resident budget — one V tile means the
     kernel copies Eφ once per B-tile instead of once per sweep. The
     promotion used to be silent; this mirror of ``csr_effective_block_t``
-    exposes it so tune records, the roofline HBM model, and telemetry
-    report the tile that ran, never a requested-but-ignored ``block_v``.
+    exposes it so tune records and telemetry report the tile that ran,
+    never a requested-but-ignored ``block_v``.
 
     Returns ``(block_b, block_v, v_resident)``.
     """
@@ -285,7 +279,7 @@ def csr_effective_block_t(t: int, k: int, stream_bytes: int = 4,
     the whole (T, K) Eφ token stream fits the resident budget it becomes
     ONE tile, so the pipeline fetches it once per call instead of once
     per sweep — the default token budgets are chosen to sit inside this
-    regime. Exposed so the BENCH_estep HBM model counts the same grid.
+    regime.
     """
     t_aligned = _round_up(t, 128)
     kp = _round_up(k, 128)
@@ -396,70 +390,6 @@ def memo_correction_pallas_csr(cfg: LDAConfig, exp_elog_beta: jax.Array,
     words_first = jnp.sum(jnp.where(~visited, doc_words, 0.0))
     res = EStepResult(gamma=gamma, pi=pi, sstats=snew, iters=iters)
     return correction, words_first, res
-
-
-# ---------------------------------------------------------------------------
-# legacy per-sweep path (benchmark baseline)
-# ---------------------------------------------------------------------------
-
-@partial(jax.jit, static_argnames=("cfg", "block_b", "block_v"))
-def estep_pallas_sweeps(cfg: LDAConfig, exp_elog_beta: jax.Array,
-                        token_ids: jax.Array, counts: jax.Array,
-                        gamma0: Optional[jax.Array] = None, *,
-                        block_b: int = 128, block_v: int = 512) -> EStepResult:
-    """Pre-fusion E-step: one ``pallas_call`` per sweep inside a
-    ``lax.while_loop``, jnp Eθ recomputation between sweeps, separate
-    sstats kernel, jnp token-π recovery. Kept as the BENCH_estep baseline."""
-    bsz = token_ids.shape[0]
-    v = exp_elog_beta.shape[0]
-    c = densify(token_ids, counts, v)
-    cpad, ebpad, (b, _, k) = pad_inputs(c, exp_elog_beta, block_b, block_v)
-    if gamma0 is None:
-        gamma0 = jnp.full((bsz, cfg.num_topics), cfg.alpha0 + 1.0, jnp.float32)
-    gpad = jnp.pad(gamma0, ((0, cpad.shape[0] - b), (0, ebpad.shape[1] - k)),
-                   constant_values=cfg.alpha0)
-
-    def elog_theta_exp(g):
-        # digamma expectation over the *real* topics only; padded topics
-        # carry exactly α₀ and a zero Eφ column, set their Eθ to 0.
-        real = jnp.arange(g.shape[1]) < k
-        gm = jnp.where(real, g, 0.0)
-        s = gm.sum(-1, keepdims=True)
-        et = jnp.exp(jax.scipy.special.digamma(jnp.maximum(g, 1e-10))
-                     - jax.scipy.special.digamma(s))
-        return jnp.where(real, et, 0.0)
-
-    def cond(carry):
-        _, delta, it = carry
-        return jnp.logical_and(delta > cfg.estep_tol,
-                               it < cfg.estep_max_iters)
-
-    def body(carry):
-        g, _, it = carry
-        et = elog_theta_exp(g)
-        g_new = lda_estep.estep_sweep(cpad, et, ebpad, cfg.alpha0,
-                                      block_b=block_b, block_v=block_v)
-        real = jnp.arange(g.shape[1]) < k
-        g_new = jnp.where(real, g_new, cfg.alpha0)
-        delta = jnp.abs(g_new - g).mean()
-        return g_new, delta, it + 1
-
-    init = (gpad, jnp.asarray(jnp.inf, jnp.float32), jnp.asarray(0, jnp.int32))
-    gpad, _, iters = jax.lax.while_loop(cond, body, init)
-
-    et = elog_theta_exp(gpad)
-    spad = lda_estep.sstats(cpad, et, ebpad, block_b=block_b, block_v=block_v)
-    gamma = gpad[:bsz, :k]
-    sstats_out = spad[:v, :k]
-
-    # token-aligned π for the IVI memo (identical to estep_dense)
-    etheta = et[:bsz, :k]
-    ebg = exp_elog_beta[token_ids]
-    p_tok = jnp.einsum("bk,blk->bl", etheta, ebg) + _EPS
-    pi = etheta[:, None, :] * ebg / p_tok[:, :, None]
-    pi = jnp.where(counts[:, :, None] > 0, pi, 0.0)
-    return EStepResult(gamma=gamma, pi=pi, sstats=sstats_out,
-                       iters=iters[None])
 
 
 def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,

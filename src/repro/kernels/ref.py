@@ -1,35 +1,13 @@
-"""Pure-jnp oracles for the LDA Pallas kernels.
+"""Pure-jnp oracle for the flash-attention kernel.
 
-The E-step hotspot in the dense TPU formulation (DESIGN.md §2 & §7):
-
-  P = Eθ · Eφᵀ              (B, V)   "phinorm"
-  R = C ⊘ (P + ε)           (B, V)
-  sweep:  γ' = α₀ + Eθ ⊙ (R · Eφ)            — one fixed-point iteration
-  sstats: S  = Eφ ⊙ (Rᵀ · Eθ)                — Σ_d cnt·π scattered to (V, K)
-
-Everything is two (B,V)×(V,K)-shaped MXU matmuls plus elementwise work;
-the kernels tile over V so Eφ streams HBM→VMEM exactly once per call and
-the (B, V) intermediates never materialise in HBM.
+The LDA kernels' references live with the E-step backends:
+``repro.core.estep.estep_gather`` for the padded layout and
+``repro.core.estep.estep_csr_ref`` for the flat CSR layout.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-_EPS = 1e-30  # fp32-safe (1e-100 underflows to 0)
-
-
-def estep_sweep_ref(c: jax.Array, etheta: jax.Array, eb: jax.Array,
-                    alpha0: float) -> jax.Array:
-    """One dense fixed-point sweep: γ' (B, K)."""
-    p = etheta @ eb.T + _EPS                   # (B, V)
-    return alpha0 + etheta * ((c / p) @ eb)
-
-
-def sstats_ref(c: jax.Array, etheta: jax.Array, eb: jax.Array) -> jax.Array:
-    """Expected topic-word counts for the batch: S (V, K)."""
-    p = etheta @ eb.T + _EPS                   # (B, V)
-    return eb * ((c / p).T @ etheta)
 
 
 def mha_ref(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,

@@ -9,7 +9,7 @@ Three modes:
 * ``divi`` — one D-IVI global round on the production mesh: λ / ⟨m_vk⟩
   model-sharded on V (DESIGN.md §5); per-worker memo stores and the
   streamed (W, S, B, L) batch slabs data-sharded — no corpus is device
-  state. Reports memory + roofline terms like the transformer dry-run.
+  state. Reports memory and roofline terms.
 * ``ivi`` — the single-host IVI hot step (`engines.incremental_update`)
   lowered with the fused Pallas E-step backend, plus the MemoStore
   footprint math: the device program only ever sees one mini-batch of the
@@ -41,8 +41,7 @@ from repro.dist.divi import (DIVIConfig, DIVIState, WorkerShard,
                              make_divi_round)
 from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
-
-HW = {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9}
+from repro.obs.roofline import HW
 
 # Arxiv (Table 1): 782,385 train docs, V=141,927, avg 116 words/doc.
 ARXIV = dict(num_docs=782_384, vocab=141_952,       # padded: /16 divisible

@@ -1,29 +1,19 @@
 """Training launcher.
 
-Two modes:
-  * ``lda``  — the paper's system: train LDA with MVI/SVI/IVI/S-IVI/D-IVI
-    on a synthetic paper-shaped corpus, periodic held-out LPP evaluation,
-    checkpointing.
-  * ``lm``   — transformer training: any assigned arch (reduced or full),
-    synthetic token stream, AdamW or IAG, optional mesh.
+``lda`` trains LDA with MVI/SVI/IVI/S-IVI/D-IVI on a synthetic
+paper-shaped corpus, with periodic held-out LPP evaluation and
+checkpointing.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train lda --algo ivi --corpus small
   PYTHONPATH=src python -m repro.launch.train lda --algo divi --workers 4
   PYTHONPATH=src python -m repro.launch.train lda --algo divi --workers 4 \
       --stream                     # D-IVI straight off a UCI DocStream
-  PYTHONPATH=src python -m repro.launch.train lm --arch yi-9b --reduced \
-      --steps 200 --batch 8 --seq 128
 """
 from __future__ import annotations
 
 import argparse
 import os
-import time
-
-import jax
-import jax.numpy as jnp
-import numpy as np
 
 
 def main_lda(args) -> None:
@@ -174,74 +164,6 @@ def _report_telemetry(tel, args) -> None:
         print(f"metrics: wrote {args.metrics_json}")
 
 
-def main_lm(args) -> None:
-    from repro.configs import get_config
-    from repro.models import transformer as T
-    from repro.optim import adamw, cosine_schedule, iag
-    from repro.training import TrainState, make_train_step
-
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced(seq_len_hint=args.seq)
-    rng = np.random.default_rng(args.seed)
-    params = T.init_params(cfg, jax.random.key(args.seed))
-    n = sum(x.size for x in jax.tree.leaves(params))
-    print(f"arch={cfg.name} params={n / 1e6:.2f}M")
-    if args.optimizer == "iag":
-        opt = iag(args.lr, num_shards=args.iag_shards)
-    else:
-        opt = adamw(cosine_schedule(args.lr, 10, args.steps))
-    state = TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
-    if args.optimizer == "iag":
-        def step_fn(state, batch, shard):
-            def lfn(p):
-                return T.loss_fn(cfg, p, batch)
-            (loss, m), g = jax.value_and_grad(lfn, has_aux=True)(state.params)
-            upd, os_ = opt.update(g, state.opt_state, state.params,
-                                  shard=shard)
-            from repro.optim import apply_updates
-            return TrainState(apply_updates(state.params, upd), os_,
-                              state.step + 1), m
-        step = jax.jit(step_fn)
-    else:
-        step = jax.jit(make_train_step(cfg, opt))
-
-    def sample_batch():
-        shape = ((args.batch, args.seq, cfg.num_codebooks)
-                 if cfg.modality == "audio" else (args.batch, args.seq))
-        toks = rng.integers(0, cfg.vocab_size, shape)
-        batch = {"tokens": jnp.asarray(toks)}
-        lab_len = args.seq + (cfg.num_patches if cfg.modality == "vision"
-                              else 0)
-        lab_shape = ((args.batch, lab_len, cfg.num_codebooks)
-                     if cfg.modality == "audio" else (args.batch, lab_len))
-        batch["labels"] = jnp.asarray(rng.integers(0, cfg.vocab_size,
-                                                   lab_shape))
-        if cfg.modality == "vision":
-            batch["vision_embeds"] = jnp.asarray(rng.normal(
-                0, 1, (args.batch, cfg.num_patches, cfg.d_model))
-                .astype(np.float32))
-        return batch
-
-    t0 = time.perf_counter()
-    for s in range(args.steps):
-        batch = sample_batch()
-        if args.optimizer == "iag":
-            state, metrics = step(state, batch,
-                                  jnp.asarray(s % args.iag_shards))
-        else:
-            state, metrics = step(state, batch)
-        if (s + 1) % args.log_every == 0:
-            dt = time.perf_counter() - t0
-            print(f"step={s + 1} loss={float(metrics['loss']):.4f} "
-                  f"ce={float(metrics['ce']):.4f} "
-                  f"steps_per_s={(s + 1) / dt:.2f}")
-    if args.ckpt:
-        from repro.checkpoint import save_checkpoint
-        save_checkpoint(args.ckpt, state.params, step=args.steps)
-        print("saved", args.ckpt)
-
-
 def main() -> None:
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -304,24 +226,7 @@ def main() -> None:
                      help="check the memoized bound every N updates "
                           "(O(corpus) each; 0 = only at evaluations)")
 
-    lm = sub.add_parser("lm")
-    lm.add_argument("--arch", required=True)
-    lm.add_argument("--reduced", action="store_true")
-    lm.add_argument("--steps", type=int, default=100)
-    lm.add_argument("--batch", type=int, default=4)
-    lm.add_argument("--seq", type=int, default=128)
-    lm.add_argument("--lr", type=float, default=3e-4)
-    lm.add_argument("--optimizer", default="adamw", choices=["adamw", "iag"])
-    lm.add_argument("--iag-shards", type=int, default=8)
-    lm.add_argument("--log-every", type=int, default=10)
-    lm.add_argument("--seed", type=int, default=0)
-    lm.add_argument("--ckpt", default=None)
-
-    args = ap.parse_args()
-    if args.mode == "lda":
-        main_lda(args)
-    else:
-        main_lm(args)
+    main_lda(ap.parse_args())
 
 
 if __name__ == "__main__":

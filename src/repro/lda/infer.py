@@ -18,9 +18,7 @@ scoring). This module packages that E-step for request traffic:
   consumes a ``DocStream`` / iterable of ragged documents through a
   ``BatchPacker`` and — by default — an **async double-buffered
   pipeline**: a host thread packs and stages request batch *t+1* while
-  the device runs the E-step on batch *t* (`docs/streaming.md`;
-  throughput record in ``BENCH_serve.json`` via
-  ``benchmarks/serve_bench.py``);
+  the device runs the E-step on batch *t* (`docs/streaming.md`);
 * the E-step dispatches through ``cfg.estep_backend`` — with ``pallas``
   this is the fused fixed-point kernel (`docs/estep.md`), the production
   serving configuration;
@@ -355,10 +353,8 @@ class TopicInferencer:
         blocking — jax's async dispatch keeps the device queue full — and
         only converts γ to host arrays once every batch is in flight).
         ``double_buffer=False`` is the synchronous reference path: pack →
-        run → block, one batch at a time (the baseline
-        ``benchmarks/serve_bench.py`` measures the pipelining win
-        against). Both paths run identical batches through the same jit
-        entries, so their results are bit-identical.
+        run → block, one batch at a time. Both paths run identical batches
+        through the same jit entries, so their results are bit-identical.
         """
         results: List[_Result] = []
         if double_buffer:

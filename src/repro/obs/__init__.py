@@ -30,15 +30,15 @@ so ``LDA(cfg, telemetry=True)`` turns everything on with defaults while
 power users hand in a pre-configured bundle (e.g. a ``raise``-policy
 watchdog, or a ``device_sync=True`` recorder for kernel benchmarking).
 
-See ``docs/observability.md`` for the span taxonomy, metric names, the
-trace file schema, and how to read the roofline check.
+See ``docs/observability.md`` for the span taxonomy, metric names and
+the trace file schema.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 
 from .metrics import NULL_METRICS, MetricsRegistry, NullMetrics
-from .roofline import roofline_check, roofline_from_trace, spans_by_name
+from .roofline import spans_by_name
 from .trace import (
     NULL_TRACE,
     NullSpanRecorder,
@@ -65,7 +65,7 @@ __all__ = [
     "MetricsRegistry", "NullMetrics", "NULL_METRICS",
     "ElboWatchdog", "NullElboWatchdog", "NULL_WATCHDOG",
     "BoundMonotonicityError", "ElboMonotonicityWarning",
-    "roofline_check", "roofline_from_trace", "spans_by_name",
+    "spans_by_name",
 ]
 
 

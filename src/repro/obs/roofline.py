@@ -1,51 +1,22 @@
-"""Measured-vs-modeled accounting: the roofline check.
-
-Nearly every perf claim in BENCH_estep.json / BENCH_serve.json is a
-*structural model* (HBM bytes counted from the Pallas grid, divided by a
-hardware stream rate). A model is only trustworthy while measurement
-agrees with it — this module is the join:
+"""Span aggregation and the v5e hardware table.
 
 * ``spans_by_name`` aggregates a ``SpanRecorder``'s records per span name
-  (count, total, min, mean) — the **measured** side. For kernel timings
-  the recorder should run with ``device_sync=True`` so a span measures
-  compute, not dispatch; ``min_s`` is the aggregate the check uses
-  (minimum over repetitions is the standard noise-robust estimator for
-  a deterministic workload).
-* ``roofline_check`` joins measured seconds against each kernel's modeled
-  HBM bytes: ``modeled_s = bytes / bandwidth`` is the memory-bound time,
-  and ``measured_vs_modeled = measured_s / modeled_s`` should sit near
-  1.0 for a genuinely memory-bound kernel on the modeled hardware. A
-  ratio outside ``band`` flags the kernel: **> band** means the kernel is
-  slower than its memory traffic explains (it is NOT memory-bound there —
-  compute- or overhead-dominated, and the bytes model must not be used to
-  claim speedups at that shape); **< band** means the model over-counts
-  bytes (the kernel reuses more than the model credits).
-* ``proxy_regime``: on this CPU container the Pallas kernels run in
-  interpret mode, so measured times are *Python* times and disagree with
-  the TPU HBM model by construction. The flag records that the measured
-  side is a proxy — the record is still emitted (trend tracking; the join
-  machinery is what CI exercises) but ``agrees`` is expected False and is
-  **not** a CI bar in that regime. On real TPU hardware the same call
-  becomes the model-validation gate.
+  (count, total, min, mean seconds). For kernel timings the recorder
+  should run with ``device_sync=True`` so a span measures compute, not
+  dispatch.
+* ``HW`` holds one v5e chip's peak flops and HBM and ICI bandwidths:
+  ``repro.tune``'s cost model prices bytes against it and
+  ``repro.launch.dryrun_lda`` reports roofline terms from it.
 
-``benchmarks/obs_bench.py`` drives this against the E-step kernels'
-``modeled_estep_hbm_bytes`` and emits ``BENCH_obs.json``. The hardware
-table lives HERE (``HW`` / ``HBM_GB`` — v5e figures): this module is the
-canonical home the seed roofline harness (``benchmarks/roofline.py``)
-now re-exports from, closing the "seed roofline.py is unused" loop — the
-seed harness renders the dry-run sweep AND these checks' BENCH_obs.json
-records through one table.
+Speed is measured on the chip by the benchmark in ``bench/``, whose own
+peak table is ``bench/peaks.json``.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable
 
-# v5e hardware constants — the ONE table every roofline consumer shares
-# (the seed dry-run renderer, obs_bench's measured-vs-modeled join, and
-# kernel_bench's modeled stream rates all import from here).
 HW = {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9}
-HBM_GB = 16.0   # v5e per-chip HBM
 
 
 def spans_by_name(records: Iterable[dict]) -> Dict[str, dict]:
@@ -68,74 +39,4 @@ def spans_by_name(records: Iterable[dict]) -> Dict[str, dict]:
             agg["min_s"] = dur_s
     for agg in out.values():
         agg["mean_s"] = agg["total_s"] / agg["count"]
-    return out
-
-
-def roofline_check(records: Sequence[dict], *, hbm_gbps: float,
-                   band: Tuple[float, float] = (0.5, 2.0),
-                   proxy_regime: bool = False) -> dict:
-    """Join measured kernel seconds against modeled HBM bytes.
-
-    ``records``: ``[{"name": str, "measured_s": float,
-    "modeled_hbm_bytes": int|float, ...}]`` — extra keys pass through.
-    Returns a summary dict with per-record verdicts (see module
-    docstring for how to read the flags).
-    """
-    if hbm_gbps <= 0:
-        raise ValueError("hbm_gbps must be positive")
-    lo, hi = band
-    if not (0 < lo < hi):
-        raise ValueError(f"band must be 0 < lo < hi, got {band}")
-    out: List[dict] = []
-    for r in records:
-        modeled_s = float(r["modeled_hbm_bytes"]) / (hbm_gbps * 1e9)
-        measured_s = float(r["measured_s"])
-        ratio = measured_s / modeled_s if modeled_s > 0 else math.inf
-        out.append({
-            **r,
-            "modeled_s": modeled_s,
-            "measured_vs_modeled": ratio,
-            "agrees_with_memory_bound_model": lo <= ratio <= hi,
-            "verdict": ("memory_bound" if lo <= ratio <= hi else
-                        "slower_than_memory_model" if ratio > hi else
-                        "model_overcounts_bytes"),
-        })
-    flagged = [r["name"] for r in out
-               if not r["agrees_with_memory_bound_model"]]
-    return {
-        "hbm_gbps": hbm_gbps,
-        "band": [lo, hi],
-        "proxy_regime": proxy_regime,
-        "records": out,
-        "n_records": len(out),
-        "n_agree": len(out) - len(flagged),
-        "flagged": flagged,
-    }
-
-
-def roofline_from_trace(trace_records: Iterable[dict],
-                        modeled_bytes: Dict[str, float], *,
-                        hbm_gbps: float,
-                        band: Tuple[float, float] = (0.5, 2.0),
-                        proxy_regime: bool = False,
-                        use: str = "min_s") -> dict:
-    """``roofline_check`` fed straight from a span trace.
-
-    ``modeled_bytes`` maps span names to their modeled HBM bytes; span
-    names absent from the trace are skipped (and listed under
-    ``missing_spans`` so a renamed instrumentation point cannot silently
-    empty the check).
-    """
-    agg = spans_by_name(trace_records)
-    rows, missing = [], []
-    for name, bytes_ in modeled_bytes.items():
-        if name not in agg:
-            missing.append(name)
-            continue
-        rows.append({"name": name, "measured_s": agg[name][use],
-                     "measured_calls": agg[name]["count"],
-                     "modeled_hbm_bytes": bytes_})
-    out = roofline_check(rows, hbm_gbps=hbm_gbps, band=band,
-                         proxy_regime=proxy_regime)
-    out["missing_spans"] = missing
     return out

@@ -14,8 +14,7 @@ incremental update trains on the served traffic in the background:
   measured swap-stall window;
 * `repro.serve.online` — the background IVI learner feeding it.
 
-See ``docs/serving.md`` for the architecture and semantics;
-``benchmarks/service_bench.py`` emits ``BENCH_service.json``.
+See ``docs/serving.md`` for the architecture and semantics.
 """
 from repro.serve.admission import AdmissionController, Request, Response
 from repro.serve.online import OnlineLearner

@@ -12,8 +12,8 @@ learner drives:
   swap window, then publishes to every attached inferencer with one
   ``swap_model`` call each and **measures the swap stall** (the wall time
   a concurrent request could contend on). That measured window is the
-  ``serve.swap_stall_ms`` histogram ``benchmarks/service_bench.py``
-  asserts a bound on: inference never blocks on training beyond it.
+  ``serve.swap_stall_ms`` histogram: inference never blocks on training
+  beyond it.
 
 The store is thread-safe: one learner publishing while any number of
 serving threads read is the designed case; multiple publishers serialise

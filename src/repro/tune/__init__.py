@@ -19,9 +19,8 @@ stack is bit-identical to the pre-autotune behaviour.
 Search (``repro.tune.search``) is deliberately imported lazily — it
 pulls in the kernels; the store/resolve layer is dependency-light so
 engines can import it at construction. CLI: ``python -m repro.tune``
-(tune / show / clear); benchmark: ``benchmarks/tune_bench.py`` →
-``BENCH_tune.json``. docs/tuning.md has the full story, including the
-measured-vs-modeled honesty rules.
+(tune / show / clear). docs/tuning.md has the full story, including why
+an off-chip objective is a model, never a measurement.
 """
 from __future__ import annotations
 

@@ -1,8 +1,7 @@
 """Policy-aware structural HBM cost model for the E-step kernels.
 
-The same counting rules as ``benchmarks/kernel_bench.py`` (a block is
-re-fetched only when its index-map output changes between consecutive
-grid steps; jnp intermediates cost one write + one read), generalised so
+A block is re-fetched only when its index-map output changes between
+consecutive grid steps, and jnp intermediates cost one write + one read;
 every :class:`KernelPolicy` knob the search can move is priced:
 
 * ``block_b`` — the fused fixed point re-streams Eφ once per B-tile per
@@ -23,8 +22,7 @@ every :class:`KernelPolicy` knob the search can move is priced:
 This is the *fallback* objective (tagged ``proxy_regime=True``) when no
 real accelerator is present to time; on a TPU the search times the real
 ``pallas_call`` executions instead. Modeled seconds divide bytes by the
-``repro.obs.roofline`` HW table's HBM bandwidth — the same convention as
-every BENCH_*.json.
+``repro.obs.roofline`` HW table's HBM bandwidth.
 """
 from __future__ import annotations
 

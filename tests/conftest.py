@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: tests run on the single host CPU device —
-XLA_FLAGS device-count forcing is reserved for launch/dryrun.py and the
+XLA_FLAGS device-count forcing is reserved for launch/dryrun_lda.py and the
 subprocess-based distribution tests."""
 import functools
 import inspect

@@ -192,22 +192,14 @@ def test_fused_pallas_launch_structure():
     assert fused["blk_intermediates"] == 0, fused
     assert dense_vocab_cubes(fused_corr, cfg.vocab_size) == 0
 
-    # the retired one-hot baseline DOES allocate the dense partials — the
-    # guard must be able to see them, or the zero above proves nothing
-    from repro.kernels import lda_estep
-    eb_tok = eb[corpus.token_ids]
-    et = jnp.ones((corpus.num_docs, cfg.num_topics), jnp.float32)
-    assert dense_vocab_cubes(
-        lambda: lda_estep.memo_delta_onehot(
-            corpus.token_ids, corpus.counts, eb_tok, et, cfg.vocab_size,
-            old_pi=old_pi, block_b=4),
-        cfg.vocab_size) > 0
-
-    from repro.kernels.ops import estep_pallas_sweeps
-    legacy = pallas_call_sites(
-        lambda: estep_pallas_sweeps(cfg, eb, corpus.token_ids,
-                                    corpus.counts))
-    assert legacy["under_loop"] >= 1            # the old one-launch-per-sweep
+    # per-B-tile (nb, V, K) one-hot partials DO trip the guard, or the zero
+    # above proves nothing
+    def onehot_partials():
+        hot = jax.nn.one_hot(corpus.token_ids.reshape(4, -1),
+                             cfg.vocab_size)
+        w = old_pi.reshape(4, -1, cfg.num_topics)
+        return jnp.einsum("nrv,nrk->nvk", hot, w).sum(0)
+    assert dense_vocab_cubes(onehot_partials, cfg.vocab_size) > 0
 
 
 def test_pallas_correction_long_token_axis():

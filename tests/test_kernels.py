@@ -1,73 +1,23 @@
-"""Pallas kernel validation: shape/dtype sweeps against the jnp oracles
-(interpret mode on CPU), plus the full estep_pallas vs estep_dense path."""
+"""Pallas kernel validation in interpret mode on the CPU: the fused fixed
+point's per-tile stopping, the segment scatter's grid edge cases, and the
+full estep_pallas vs estep_dense path. The tilings of
+``tests/test_kernel_tiling.py`` run in a file of their own."""
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
 from jax.experimental.pallas import tpu as pltpu
+from kernel_oracles import fixed_point_per_tile, memo_delta_ref
 
 from repro.core import LDAConfig
 from repro.core.estep import densify, estep_dense, estep_gather
 from repro.core.math import exp_dirichlet_expectation
 from repro.data import PAPER_CORPORA, make_corpus
-from repro.kernels import lda_estep, ref
+from repro.kernels import lda_estep
 from repro.kernels.ops import (estep_pallas, memo_correction_pallas,
                                pad_inputs)
-
-
-SHAPES = [
-    # (B, V, K, block_b, block_v)
-    (8, 64, 16, 8, 32),
-    (16, 256, 32, 8, 64),
-    (128, 512, 128, 128, 512),
-    (32, 768, 100, 16, 128),
-    (64, 1024, 128, 32, 256),
-    (8, 512, 64, 8, 512),      # single V tile
-    (128, 128, 128, 64, 64),
-]
-
-
-@pytest.mark.parametrize("b,v,k,bb,bv", SHAPES)
-def test_sweep_kernel_matches_ref(b, v, k, bb, bv, rng):
-    c = jnp.asarray(rng.poisson(0.3, (b, v)).astype(np.float32))
-    et = jnp.asarray(rng.gamma(1.0, 1.0, (b, k)).astype(np.float32))
-    eb = jnp.asarray(rng.gamma(1.0, 1.0, (v, k)).astype(np.float32))
-    got = lda_estep.estep_sweep(c, et, eb, 0.5, block_b=bb, block_v=bv)
-    want = ref.estep_sweep_ref(c, et, eb, 0.5)
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("b,v,k,bb,bv", SHAPES)
-def test_sstats_kernel_matches_ref(b, v, k, bb, bv, rng):
-    c = jnp.asarray(rng.poisson(0.3, (b, v)).astype(np.float32))
-    et = jnp.asarray(rng.gamma(1.0, 1.0, (b, k)).astype(np.float32))
-    eb = jnp.asarray(rng.gamma(1.0, 1.0, (v, k)).astype(np.float32))
-    got = lda_estep.sstats(c, et, eb, block_b=bb, block_v=bv)
-    want = ref.sstats_ref(c, et, eb)
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-
-
-@settings(max_examples=10, deadline=None)
-@given(seed=st.integers(0, 10_000),
-       b=st.sampled_from([4, 8, 16]),
-       v=st.sampled_from([96, 160, 320]),
-       k=st.sampled_from([8, 24, 100]))
-def test_kernel_property_random_shapes(seed, b, v, k):
-    rng = np.random.default_rng(seed)
-    c = jnp.asarray(rng.poisson(0.5, (b, v)).astype(np.float32))
-    et = jnp.asarray(rng.gamma(0.7, 2.0, (b, k)).astype(np.float32))
-    eb = jnp.asarray(rng.gamma(0.7, 2.0, (v, k)).astype(np.float32))
-    bb = b
-    bv = v // 2 if v % 2 == 0 else v
-    got = lda_estep.estep_sweep(c, et, eb, 0.5, block_b=bb, block_v=bv)
-    want = ref.estep_sweep_ref(c, et, eb, 0.5)
-    np.testing.assert_allclose(got, want, rtol=5e-5, atol=5e-5)
-    gs = lda_estep.sstats(c, et, eb, block_b=bb, block_v=bv)
-    ws = ref.sstats_ref(c, et, eb)
-    np.testing.assert_allclose(gs, ws, rtol=5e-5, atol=5e-5)
 
 
 def test_estep_pallas_full_path():
@@ -84,39 +34,6 @@ def test_estep_pallas_full_path():
     np.testing.assert_allclose(r1.gamma, r2.gamma, rtol=1e-3, atol=1e-3)
     np.testing.assert_allclose(r1.sstats, r2.sstats, rtol=1e-2, atol=1e-3)
     np.testing.assert_allclose(r1.pi, r2.pi, rtol=1e-3, atol=1e-4)
-
-
-def _fixed_point_per_tile(c, eb, g0, alpha0, tol, max_iters, k_real,
-                          b_real, block_b, block_v):
-    """The fused fixed point's update, one B-tile at a time in jnp: the
-    kernel's own E[θ]/ψ, the V-tiles summed in ascending order, the same
-    mean |Δγ| over the tile's real rows. Returns (γ, E[θ], sweeps)."""
-    c, eb = c.astype(jnp.float32), eb.astype(jnp.float32)
-    real = jnp.arange(g0.shape[1])[None, :] < k_real
-    gammas, sweeps = [], []
-    for i in range(c.shape[0] // block_b):
-        rows = slice(i * block_b, (i + 1) * block_b)
-        rows_real = min(max(b_real - i * block_b, 1), block_b)
-        g, t = g0[rows], 0
-        while t < max_iters:
-            et = lda_estep._exp_elog_theta(g, k_real)
-            acc = jnp.zeros_like(g)
-            for j in range(0, c.shape[1], block_v):
-                ebj = eb[j:j + block_v]
-                p = jax.lax.dot_general(
-                    et, ebj, (((1,), (1,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST) + 1e-30
-                acc = acc + jax.lax.dot(c[rows, j:j + block_v] / p, ebj,
-                                        precision=jax.lax.Precision.HIGHEST)
-            g_new = jnp.where(real, alpha0 + et * acc, alpha0)
-            delta = float(jnp.abs(g_new - g).sum()) / (rows_real * k_real)
-            g, t = g_new, t + 1
-            if delta <= tol:
-                break
-        gammas.append(g)
-        sweeps.append(t)
-    gamma = jnp.concatenate(gammas)
-    return gamma, lda_estep._exp_elog_theta(gamma, k_real), sweeps
 
 
 def _tiles_batch(b=20, v=300, k=8, l=24, seed=0):
@@ -170,7 +87,7 @@ def test_fixed_point_stops_per_tile(block_v, stream, workers):
         interpret=pltpu.InterpretParams(dma_execution_mode="on_wait"))
     for got, want in zip(late, (gamma, et, iters)):
         np.testing.assert_array_equal(got, want)
-    want_g, want_et, want_sweeps = _fixed_point_per_tile(
+    want_g, want_et, want_sweeps = fixed_point_per_tile(
         *args, block_b, block_v)
     assert np.asarray(iters).ravel().tolist() == want_sweeps
     assert want_sweeps[0] < want_sweeps[1] < cfg.estep_max_iters
@@ -201,44 +118,6 @@ def test_fixed_point_stops_per_tile(block_v, stream, workers):
                                  jax.tree_util.tree_leaves(one)):
                 np.testing.assert_allclose(got[w], want, rtol=1e-6,
                                            atol=1e-6)
-
-
-def _memo_delta_refs(ids, cnts, ebt, et, v):
-    p = (et[:, None, :] * ebt).sum(-1) + 1e-30
-    pi = jnp.where(cnts[:, :, None] > 0,
-                   et[:, None, :] * ebt / p[:, :, None], 0.0)
-    flat = ids.reshape(-1)
-    k = et.shape[1]
-    snew = jnp.zeros((v, k)).at[flat].add(
-        (cnts[:, :, None] * pi).reshape(-1, k))
-    return pi, snew
-
-
-@pytest.mark.parametrize("b,l,block_b", [
-    (64, 32, 16),    # nb = 4: the multi-partial reduction path
-    (32, 512, 32),   # VMEM guard halves block_b (32 → 4 at L=512, K=128)
-])
-def test_memo_delta_onehot_multi_tile_partials(b, l, block_b, rng):
-    """The retired (nb, V, K) partial scheme (the benchmark baseline) must
-    still match the jnp scatter with nb ≥ 2 B-tiles and when the VMEM
-    guard shrinks the tile — shapes at which the old cross-tile output
-    accumulation (TPU-undefined) was actually exercised."""
-    v, k = 700, 128
-    ids = jnp.asarray(rng.integers(0, v, (b, l)).astype(np.int32))
-    cnts = jnp.asarray(rng.poisson(1.0, (b, l)).astype(np.float32))
-    ebt = jnp.asarray(rng.gamma(1.0, 1.0, (b, l, k)).astype(np.float32))
-    et = jnp.asarray(rng.gamma(1.0, 1.0, (b, k)).astype(np.float32))
-    opi = jnp.asarray(rng.random((b, l, k)).astype(np.float32))
-    assert b // lda_estep.delta_effective_block_b(
-        b, l, k, block_b=block_b) >= 2          # the shapes must fan out
-    pi, snew, sold = lda_estep.memo_delta_onehot(ids, cnts, ebt, et, v,
-                                                 old_pi=opi, block_b=block_b)
-    pref, sref = _memo_delta_refs(ids, cnts, ebt, et, v)
-    soldref = jnp.zeros((v, k)).at[ids.reshape(-1)].add(
-        (cnts[:, :, None] * opi).reshape(-1, k))
-    np.testing.assert_allclose(pi, pref, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(snew, sref, rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(sold, soldref, rtol=1e-4, atol=1e-4)
 
 
 def _scatter_case_ids(kind, b, l, v, rng):
@@ -306,7 +185,7 @@ def test_memo_delta_segment_grid(b, l, v, kwargs, kind, rng):
     ebt = jnp.asarray(rng.gamma(1.0, 1.0, (b, l, k)).astype(np.float32))
     et = jnp.asarray(rng.gamma(1.0, 1.0, (b, k)).astype(np.float32))
     opi = jnp.asarray(rng.random((b, l, k)).astype(np.float32))
-    pref, sref = _memo_delta_refs(ids, cnts, ebt, et, v)
+    pref, sref = memo_delta_ref(ids, cnts, ebt, et, v)
     soldref = jnp.zeros((v, k)).at[ids.reshape(-1)].add(
         (cnts[:, :, None] * opi).reshape(-1, k))
     for quantize in (False, True):
@@ -328,21 +207,6 @@ def test_memo_delta_segment_grid(b, l, v, kwargs, kind, rng):
         np.testing.assert_allclose(snew, sexp, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(snew1, sexp, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(sold, soldref, rtol=1e-4, atol=1e-4)
-
-
-def test_memo_delta_matches_onehot_baseline(rng):
-    """Segment-sum and the retired one-hot baseline agree bit-for-bit in
-    what they compute (π) and to fp32 summation tolerance in the masses —
-    the 'measured, not asserted' bridge BENCH_estep quantifies."""
-    b, l, v, k = 16, 48, 500, 64
-    ids = jnp.asarray(rng.integers(0, v, (b, l)).astype(np.int32))
-    cnts = jnp.asarray(rng.poisson(1.0, (b, l)).astype(np.float32))
-    ebt = jnp.asarray(rng.gamma(1.0, 1.0, (b, l, k)).astype(np.float32))
-    et = jnp.asarray(rng.gamma(1.0, 1.0, (b, k)).astype(np.float32))
-    seg = lda_estep.memo_delta(ids, cnts, ebt, et, v, quantize=True)
-    one = lda_estep.memo_delta_onehot(ids, cnts, ebt, et, v, quantize=True)
-    np.testing.assert_array_equal(np.asarray(seg[0]), np.asarray(one[0]))
-    np.testing.assert_allclose(seg[1], one[1], rtol=1e-4, atol=1e-4)
 
 
 def test_segment_scatter_blocks_policy():
@@ -421,19 +285,6 @@ def test_memo_delta_sorted_under_vmap(rng):
                                     opi[x])):
             np.testing.assert_array_equal(np.asarray(got[x]),
                                           np.asarray(want))
-
-
-def test_delta_effective_block_b_guard():
-    """The VMEM guard halves the B-tile for long token axes and always
-    returns a divisor of B."""
-    f = lda_estep.delta_effective_block_b
-    assert f(128, 64, 128) == 32           # fits at the default
-    assert f(128, 128, 128) == 16          # production L halves once
-    assert f(128, 512, 128) == 4
-    assert f(12, 40, 16) == 12             # small batch: capped at B
-    for b, l in [(96, 512), (32, 1024), (12, 512)]:
-        bb = f(b, l, 128)
-        assert b % bb == 0, (b, l, bb)
 
 
 def test_kernel_padding_exactness():

@@ -357,6 +357,44 @@ def test_enabled_telemetry_matches_and_records(tiny_corpus):
     assert tel.metrics.value("train.effective_topics") > 0
 
 
+def test_stream_fit_telemetry_bit_identical(tiny_corpus):
+    """A streamed facade run with the default live bundle (spans, metrics,
+    watchdog) ends on the λ of the same run with telemetry off, bit for
+    bit."""
+    from repro.data.stream import CorpusDocStream
+    corpus, spec = tiny_corpus
+    lams = []
+    for telemetry in (None, True):
+        lda = LDA(num_topics=4, vocab_size=spec.vocab_size,
+                  estep_max_iters=15, algo="ivi", batch_size=16, seed=0,
+                  telemetry=telemetry)
+        lda.fit(CorpusDocStream(corpus), epochs=2)
+        lams.append(np.asarray(lda.lam))
+    np.testing.assert_array_equal(*lams)
+
+
+@pytest.mark.parametrize("layout", ["padded", "csr"])
+def test_device_synced_solve_spans_round_trip(tiny_corpus, tmp_path,
+                                              layout):
+    """A Pallas-backend run under a device-synced recorder records one
+    ``train/solve`` span per step in either layout, and its trace survives
+    JSONL → validation → Chrome conversion record for record."""
+    from repro.data.stream import CorpusDocStream
+    corpus, spec = tiny_corpus
+    tel = Telemetry(trace=SpanRecorder(device_sync=True))
+    lda = LDA(num_topics=spec.num_topics, vocab_size=spec.vocab_size,
+              estep_max_iters=15, estep_backend="pallas", algo="ivi",
+              batch_size=32, seed=0, layout=layout, telemetry=tel)
+    lda.fit(CorpusDocStream(corpus), epochs=1)
+    solve = spans_by_name(tel.trace.records)["train/solve"]
+    assert solve["count"] == tel.metrics.total("train.batches") > 0
+    assert solve["min_s"] > 0
+    jsonl = str(tmp_path / "t.jsonl")
+    n = tel.trace.dump_jsonl(jsonl)
+    assert n == tel.trace.num_records == validate_jsonl(jsonl)
+    assert chrome_trace_from_jsonl(jsonl, str(tmp_path / "t.json")) == n
+
+
 @pytest.mark.parametrize("backend,layout,block_v,steps", [
     ("pallas", "padded", None, (5, 6)),   # V = 250 in one chunk: 5 + 1
     ("pallas", "padded", 128, (10, 7)),   # 2 chunks × 5 row tiles → 5 + 2

@@ -535,6 +535,49 @@ def test_service_online_versions_advance(tiny_lda):
     assert max(store.swap_stalls_ms()) < 50.0
 
 
+@pytest.mark.parametrize("shape", ["poisson", "onoff"])
+def test_service_conserves_requests(tiny_lda, shape):
+    """Under steady Poisson and bursty ON/OFF arrivals every offered
+    request is served or shed, every served response carries a model
+    version, and the SLO report validates."""
+    inf = tiny_lda.inferencer(batch_size=8)
+    docs = _ragged(24, seed=20)
+    rate = 400.0
+    arrivals = (poisson_arrivals(len(docs), rate, seed=0)
+                if shape == "poisson" else
+                onoff_arrivals(len(docs), rate, on_s=8.0 / rate,
+                               off_s=8.0 / rate, seed=0))
+    svc = ServingService(inf, config=ServiceConfig(flush_timeout_s=0.005))
+    responses = svc.run(requests_from_docs(docs, arrivals))
+    rep = validate_slo_report(svc.slo_report())
+    assert len(responses) == rep["offered"] == len(docs)
+    assert rep["served"] + rep["shed"] == len(docs) and rep["pending"] == 0
+    assert rep["conservation_ok"] and rep["every_response_versioned"]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_online_learning_beats_frozen_model(seed):
+    """A model trained one pass over a quarter of the corpus, then served
+    the whole training split with the online learner attached, scores
+    higher on held-out documents than the frozen model."""
+    frozen = LDA(num_topics=8, vocab_size=SPEC.vocab_size,
+                 estep_max_iters=20, algo="ivi", seed=seed)
+    frozen.fit(make_corpus(SPEC, split="train", seed=seed, scale=0.25),
+               epochs=1)
+    train = make_corpus(SPEC, split="train", seed=seed)
+    test = make_corpus(SPEC, split="test", seed=seed)
+    docs = list(CorpusDocStream(train).iter_from(0))
+    inf = frozen.inferencer(batch_size=16)
+    learner = OnlineLearner(frozen.cfg, SnapshotStore(inf),
+                            lam0=np.asarray(frozen.lam), seed=seed)
+    svc = ServingService(inf, config=ServiceConfig(flush_timeout_s=0.005),
+                         learner=learner)
+    svc.run(requests_from_docs(docs, replay_arrivals(len(docs))))
+    assert learner.stream.appended == len(docs)
+    learner.drain(passes=3)
+    assert learner.model.score(test, seed=0) > frozen.score(test, seed=0)
+
+
 def test_slo_report_attainment_and_validation(tiny_lda):
     inf = tiny_lda.inferencer(batch_size=8)
     svc = ServingService(inf, config=ServiceConfig(

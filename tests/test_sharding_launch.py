@@ -1,6 +1,6 @@
 """Sharding rules + launch machinery (no real multi-device needed:
-AbstractMesh provides shape/axis metadata for the spec rules; the actual
-512-device lowering is covered by launch/dryrun.py runs)."""
+AbstractMesh provides shape/axis metadata for the spec rules; no launcher
+lowers these rules over a real device mesh)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
